@@ -18,6 +18,10 @@ boundaries:
      (the warm path replays the very same XLA executable, so this holds
      on every backend, not just CPU).
 
+Both children run with JAX's persistent compilation cache off, so the
+cold child compiles from scratch whatever ``JAX_COMPILATION_CACHE_DIR``
+says; each prints that it did.
+
 Time-to-first-result is measured *inside* each child from after process
 bootstrap (interpreter + jax import) to the first completed result:
 import cost is identical on both sides and is not what the store
@@ -61,9 +65,15 @@ def _child(store_dir: str, out_npy: str, report_json: str) -> None:
     registration (autotune or store ranking hit), and the first dispatch
     (jit+AOT compile or store executable load).
     """
+    import jax
+
     from repro.core.dsl import parse
     from repro.serve import StencilRequest, StencilServer
 
+    # only the design store may warm a child: a compile-cache hit would
+    # pass for a store hit in the cold child's timing
+    jax.config.update("jax_enable_compilation_cache", False)
+    print("cold_start child: persistent compilation cache off", flush=True)
     spec = parse(DSL)
     rng = np.random.default_rng(42)
     arrays = {
@@ -94,7 +104,6 @@ def _child(store_dir: str, out_npy: str, report_json: str) -> None:
 def _spawn(store_dir: str, out_npy: str, report_json: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = f"{ROOT / 'src'}:{ROOT}"
-    env.setdefault("JAX_PLATFORMS", "cpu")
     subprocess.run(
         [sys.executable, str(ROOT / "benchmarks" / "cold_start.py"),
          "--child", store_dir, out_npy, report_json],

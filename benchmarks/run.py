@@ -19,6 +19,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.compat import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (best_config, intensity, lm_roofline,
                             model_accuracy, parallelism_sweep,
                             serving_throughput, single_pe, speedup_vs_soda)

@@ -1,27 +1,21 @@
 #!/usr/bin/env python
-"""Repo lint: version-sensitive jax APIs live only in src/repro/compat.py.
+"""Repo lint: jax APIs outside jax's stable core live only in
+src/repro/compat.py.
 
-The ROADMAP's version policy pins every jax surface that moved between
-0.4.x and current releases behind one shim module, so a jax upgrade is a
-one-file change.  This ast-based check enforces it: outside compat.py no
-module may
+Every experimental, private or recently renamed jax surface the repo
+uses goes through one module, so a jax upgrade is a one-file change
+(ROADMAP.md, "Supported jax versions").  This ast-based check enforces
+it: outside compat.py no module may
 
-  * import ``shard_map`` from jax (``from jax import shard_map``,
-    ``from jax.experimental.shard_map import ...``), or touch
-    ``jax.experimental.shard_map`` / ``jax.shard_map`` attributes;
-  * use ``lax.pcast`` / ``lax.pvary`` (the replication-typing rename);
-  * build element-indexed BlockSpecs directly (``pl.Element``,
-    ``pl.Unblocked``, or an ``indexing_mode=`` keyword) instead of
+  * import ``shard_map`` from jax or touch ``jax.shard_map``;
+  * use ``lax.pcast`` (the shard_map replication-typing cast);
+  * build element-indexed BlockSpecs from ``pl.Element`` instead of
     ``repro.compat.element_block_spec``;
-  * pass ``check_rep=``/``check_vma=`` to anything that was not
-    imported from ``repro.compat`` (the shim normalises the kwarg name);
-  * touch the AOT export/serialize surface the persistent design store
-    is built on — ``jax.experimental.serialize_executable`` and
-    ``jax.export`` / ``jax.experimental.export`` — instead of
-    ``repro.compat.aot_compile`` / ``aot_serialize`` /
-    ``aot_deserialize`` (these APIs moved between jax releases and the
-    store must keep loading with a recompile fallback when they are
-    absent).
+  * touch ``jax.experimental.serialize_executable`` or ``jax.export``
+    instead of ``repro.compat.aot_compile`` / ``aot_serialize`` /
+    ``aot_deserialize`` (the persistent design store's AOT surface);
+  * import from ``jax._src`` (private; ``compat.tpu_chips_on_host`` is
+    the one sanctioned use).
 
 Exit 1 with file:line findings on violation, 0 when clean.
 """
@@ -35,6 +29,8 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 SCAN_DIRS = ("src", "tests", "benchmarks", "examples", "scripts")
 ALLOWED = {ROOT / "src" / "repro" / "compat.py"}
 
+_AOT = "repro.compat.aot_serialize/aot_deserialize"
+
 
 def _dotted(node: ast.AST) -> str:
     """Best-effort dotted name of an attribute/name chain."""
@@ -47,108 +43,65 @@ def _dotted(node: ast.AST) -> str:
     return ".".join(reversed(parts))
 
 
+def _module_finding(mod: str, name: str = "") -> str | None:
+    """What is wrong with importing ``name`` from module ``mod`` (or the
+    module itself when ``name`` is empty), if anything."""
+    if mod.startswith("jax._src"):
+        return f"private {mod!r} import; go through repro.compat"
+    if "shard_map" in mod or name == "shard_map":
+        return f"direct shard_map import from {mod!r}; use repro.compat.shard_map"
+    if name in ("pcast", "pvary"):
+        return f"direct {name} import from {mod!r}; use repro.compat.pvary"
+    if "serialize_executable" in mod or name == "serialize_executable":
+        return f"direct serialize_executable import from {mod!r}; use {_AOT}"
+    if mod in ("jax.export", "jax.experimental.export") or (
+        name == "export" and mod in ("jax", "jax.experimental")
+    ):
+        return f"direct jax export import from {mod!r}; use {_AOT}"
+    return None
+
+
 def check_file(path: pathlib.Path) -> list[str]:
     tree = ast.parse(path.read_text(), filename=str(path))
     rel = path.relative_to(ROOT)
     findings: list[str] = []
-    compat_names: set[str] = set()
 
-    def flag(node: ast.AST, msg: str) -> None:
-        findings.append(f"{rel}:{node.lineno}: {msg}")
+    def flag(node: ast.AST, msg: str | None) -> None:
+        if msg:
+            findings.append(f"{rel}:{node.lineno}: {msg}")
 
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
             mod = node.module or ""
-            if mod == "repro.compat" or mod.endswith(".compat"):
-                compat_names.update(a.asname or a.name for a in node.names)
-                continue
             if mod.startswith("jax"):
                 for a in node.names:
-                    if a.name == "shard_map" or "shard_map" in mod:
-                        flag(node, (
-                            f"direct shard_map import from {mod!r}; use "
-                            "repro.compat.shard_map"
-                        ))
-                    if a.name in ("pcast", "pvary"):
-                        flag(node, (
-                            f"direct {a.name} import from {mod!r}; use "
-                            "repro.compat.pvary"
-                        ))
-                    if (
-                        a.name == "serialize_executable"
-                        or "serialize_executable" in mod
-                    ):
-                        flag(node, (
-                            f"direct serialize_executable import from "
-                            f"{mod!r}; use repro.compat.aot_serialize/"
-                            "aot_deserialize"
-                        ))
-                    if a.name == "export" and mod in (
-                        "jax", "jax.experimental",
-                    ) or mod in ("jax.export", "jax.experimental.export"):
-                        flag(node, (
-                            f"direct jax export import from {mod!r}; use "
-                            "repro.compat.aot_serialize/aot_deserialize"
-                        ))
+                    flag(node, _module_finding(mod, a.name))
         elif isinstance(node, ast.Import):
             for a in node.names:
-                if "shard_map" in a.name:
-                    flag(node, (
-                        f"direct import of {a.name!r}; use "
-                        "repro.compat.shard_map"
-                    ))
-                if "serialize_executable" in a.name or a.name in (
-                    "jax.export", "jax.experimental.export",
-                ):
-                    flag(node, (
-                        f"direct import of {a.name!r}; use "
-                        "repro.compat.aot_serialize/aot_deserialize"
-                    ))
+                if a.name.startswith("jax"):
+                    flag(node, _module_finding(a.name))
         elif isinstance(node, ast.Attribute):
             dotted = _dotted(node)
-            if dotted.endswith("experimental.shard_map") or dotted in (
-                "jax.shard_map",
+            if dotted == "jax.shard_map" or dotted.endswith(
+                "experimental.shard_map"
             ):
-                flag(node, (
-                    f"direct use of {dotted}; use repro.compat.shard_map"
-                ))
+                flag(node, f"direct use of {dotted}; use repro.compat.shard_map")
             elif dotted.endswith("experimental.serialize_executable") or (
                 dotted in ("jax.export", "jax.experimental.export")
             ):
-                flag(node, (
-                    f"direct use of {dotted}; use repro.compat."
-                    "aot_serialize/aot_deserialize"
-                ))
+                flag(node, f"direct use of {dotted}; use {_AOT}")
             elif node.attr in ("pcast", "pvary") and dotted.startswith(
                 ("lax.", "jax.lax.")
             ):
-                flag(node, (
-                    f"direct use of {dotted}; use repro.compat.pvary"
-                ))
-            elif node.attr in ("Element", "Unblocked") and dotted.split(
-                "."
-            )[0] in ("pl", "pallas") or dotted.endswith(
-                ("pallas.Element", "pallas.Unblocked")
+                flag(node, f"direct use of {dotted}; use repro.compat.pvary")
+            elif node.attr == "Element" and (
+                dotted.split(".")[0] in ("pl", "pallas")
+                or dotted.endswith("pallas.Element")
             ):
                 flag(node, (
                     f"direct use of {dotted}; use "
                     "repro.compat.element_block_spec"
                 ))
-        elif isinstance(node, ast.Call):
-            callee = _dotted(node.func)
-            for kw in node.keywords:
-                if kw.arg == "indexing_mode":
-                    flag(node, (
-                        "indexing_mode= BlockSpec keyword; use "
-                        "repro.compat.element_block_spec"
-                    ))
-                elif kw.arg in ("check_rep", "check_vma") and (
-                    callee.split(".")[0] not in compat_names
-                ):
-                    flag(node, (
-                        f"{kw.arg}= passed to {callee or '<call>'}, which "
-                        "is not the repro.compat.shard_map shim"
-                    ))
     return findings
 
 

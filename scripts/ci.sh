@@ -25,8 +25,9 @@ else
 fi
 
 echo "== lint: compat imports =="
-# ast-based version-policy guard: version-sensitive jax APIs (shard_map,
-# check_rep/check_vma, element-indexed BlockSpecs) only via repro/compat.py
+# ast-based version-policy guard: experimental/private jax APIs (shard_map,
+# pcast, element-indexed BlockSpecs, executable serialization, jax._src)
+# only via repro/compat.py
 python scripts/check_compat_imports.py
 
 echo "== lint: stock kernels + example DSL =="

@@ -1,246 +1,82 @@
-"""Single-point jax version compatibility shim.
+"""The one module that touches jax APIs outside its stable core.
 
-Supported-version policy (see ROADMAP.md): the repo pins the oldest
-supported toolchain, **jax 0.4.37**, and tracks newer jax releases by
-feature-detecting the handful of APIs that moved or were renamed since.
-Everything version-sensitive is funnelled through this module so a jax
-upgrade is a one-file change; no other module may import `shard_map`,
-query an axis size, or build an element-indexed Pallas ``BlockSpec``
-directly.
+Supported-version policy (see ROADMAP.md): the repo runs on **jax
+0.9.0** with libtpu 0.0.34, the toolchain CI pins and the TPU hosts
+carry.  Every API below is experimental, private or was renamed in
+recent releases, so it is funnelled through this module; no other
+module may import it directly (``scripts/check_compat_imports.py``
+enforces it), and a jax upgrade stays a one-file change.
 
-Shimmed surface:
+  =====================  ==============================================
+  name                   jax spelling
+  =====================  ==============================================
+  ``shard_map``          ``jax.shard_map``
+  ``axis_size(name)``    ``lax.axis_size(name)``
+  ``pvary(x, names)``    ``lax.pcast(x, names, to="varying")``
+  ``element_block_spec`` ``pl.BlockSpec`` with ``pl.Element`` dims
+  AOT persistence        ``jax.experimental.serialize_executable``
+  ``tpu_chips_on_host``  ``jax._src.hardware_utils`` (PCI scan)
+  ``use_compile_cache``  ``jax_compilation_cache_dir`` config
+  =====================  ==============================================
 
-  =====================  ==========================  =======================
-  name                   jax >= 0.6 spelling         jax 0.4.37 spelling
-  =====================  ==========================  =======================
-  ``shard_map``          ``jax.shard_map``           ``jax.experimental.
-                                                     shard_map.shard_map``
-  ``axis_size(name)``    ``lax.axis_size(name)``     ``lax.psum(1, name)``
-                                                     (constant-folded to a
-                                                     Python int)
-  ``pvary(x, names)``    ``lax.pcast(x, names,       identity (0.4.x rep
-                         to="varying")``             tracking degrades loop
-                                                     carries automatically)
-  ``element_block_spec`` ``pl.BlockSpec`` with       ``pl.BlockSpec(...,
-                         ``pl.Element`` dims         indexing_mode=
-                                                     pl.Unblocked())``
-  AOT persistence        ``jax.experimental.         same, or ``jax.export``
-                         serialize_executable``      StableHLO when executable
-                                                     (de)serialization is
-                                                     missing, or ``None``
-  =====================  ==========================  =======================
-
-The AOT tier feeds the persistent design store
-(:mod:`repro.runtime.store`): compiled executables are serialized with
-the best mechanism the installed jax offers, in order of preference
-
-  1. ``jax.experimental.serialize_executable`` — the whole XLA
-     executable; deserialization skips tracing *and* compilation
-     (milliseconds to first result);
-  2. ``jax.export`` — portable StableHLO; deserialization skips Python
-     tracing but still pays XLA compilation on first call;
-  3. neither — the store persists rankings only and warm starts
-     recompile from the persisted ranking (still skipping autotune).
-
-No module outside this file may import either API directly
-(``scripts/check_compat_imports.py`` enforces it).
+The AOT surface feeds the persistent design store
+(:mod:`repro.runtime.store`): a compiled executable is serialized whole,
+and deserializing it skips tracing *and* compilation (milliseconds to
+first result).
 """
 from __future__ import annotations
 
+import os
 import pickle
-import re
+from pathlib import Path
 from typing import Callable, Sequence
 
 import jax
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental import serialize_executable as _se
+
+shard_map = jax.shard_map
+axis_size = lax.axis_size
 
 
-def _parse_version(v: str) -> tuple[int, ...]:
-    return tuple(int(x) for x in re.findall(r"\d+", v)[:3])
-
-
-JAX_VERSION: tuple[int, ...] = _parse_version(jax.__version__)
-
-# Oldest toolchain the repo promises to run on (the pinned CI version).
-MIN_SUPPORTED_JAX: tuple[int, ...] = (0, 4, 37)
-
-
-# --------------------------------------------------------------------------
-# shard_map: jax.shard_map (>=0.6) vs jax.experimental.shard_map (0.4.x)
-# --------------------------------------------------------------------------
-
-try:
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_KWARGS = None
-
-
-def shard_map(f=None, **kwargs):
-    """`shard_map` with the replication-check flag name normalised.
-
-    Newer jax renamed ``check_rep`` to ``check_vma``; callers may pass
-    either and the one the installed jax understands is forwarded.
-    """
-    global _SHARD_MAP_KWARGS
-    if _SHARD_MAP_KWARGS is None:
-        import inspect
-
-        _SHARD_MAP_KWARGS = frozenset(
-            inspect.signature(_shard_map).parameters
-        )
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        name = "check_vma" if "check_vma" in _SHARD_MAP_KWARGS else "check_rep"
-        kwargs[name] = check
-    if f is None:
-        return lambda g: _shard_map(g, **kwargs)
-    return _shard_map(f, **kwargs)
-
-
-# --------------------------------------------------------------------------
-# axis_size: lax.axis_size appeared after 0.4.37
-# --------------------------------------------------------------------------
-
-if hasattr(lax, "axis_size"):
-
-    def axis_size(axis_name: str) -> int:
-        """Size of a mapped mesh axis, as a concrete Python int."""
-        return lax.axis_size(axis_name)
-
-else:
-
-    def axis_size(axis_name: str) -> int:
-        """Size of a mapped mesh axis, as a concrete Python int.
-
-        ``psum`` of a non-tracer constant is folded to ``constant *
-        axis_size`` at trace time, so this returns a plain int usable in
-        Python control flow (e.g. building ppermute tables).
-        """
-        return lax.psum(1, axis_name)
-
-
-# --------------------------------------------------------------------------
-# pvary: mark a value as device-varying for shard_map replication typing
-# --------------------------------------------------------------------------
-
-if hasattr(lax, "pcast"):
-
-    def pvary(x, axis_names: Sequence[str]):
-        """Cast ``x`` to device-varying along ``axis_names``."""
-        return lax.pcast(x, tuple(axis_names), to="varying")
-
-elif hasattr(lax, "pvary"):
-
-    def pvary(x, axis_names: Sequence[str]):
-        return lax.pvary(x, tuple(axis_names))
-
-else:
-
-    def pvary(x, axis_names: Sequence[str]):
-        """No-op on jax 0.4.x: shard_map's replication checker computes a
-        fixpoint over loop carries there, so pre-casting is unnecessary."""
-        return x
+def pvary(x, axis_names: Sequence[str]):
+    """Cast ``x`` to device-varying along ``axis_names`` (shard_map
+    replication typing of loop carries)."""
+    return lax.pcast(x, tuple(axis_names), to="varying")
 
 
 # --------------------------------------------------------------------------
 # AOT compile / serialize / deserialize (persistent design store)
 # --------------------------------------------------------------------------
 
-
-def _detect_serialize_executable():
-    try:
-        from jax.experimental import serialize_executable as se
-    except ImportError:
-        return None
-    if hasattr(se, "serialize") and hasattr(se, "deserialize_and_load"):
-        return se
-    return None
-
-
-def _detect_export():
-    try:
-        from jax import export as ex  # jax >= 0.4.30 spelling
-    except ImportError:
-        try:
-            from jax.experimental import export as ex  # older spelling
-        except ImportError:
-            return None
-    if hasattr(ex, "deserialize"):
-        return ex
-    return None
-
-
-_SERIALIZE_EXECUTABLE = _detect_serialize_executable()
-_EXPORT = _detect_export()
-
-#: The executable-serialization tier the installed jax supports:
-#: "executable" (whole XLA executable, ms warm start), "stablehlo"
-#: (portable export, warm start still compiles), or None (rankings-only
-#: persistence; warm starts recompile but skip autotune).
-AOT_KIND: str | None = (
-    "executable" if _SERIALIZE_EXECUTABLE is not None
-    else "stablehlo" if _EXPORT is not None
-    else None
-)
+#: The executable-serialization kind the store records with each entry.
+AOT_KIND = "executable"
 
 
 def aot_compile(jitted, sample_args):
-    """Explicit AOT compile of a jitted callable for concrete/abstract args.
-
-    ``jit(f).lower(args).compile()`` is version-stable API; funnelled here
-    anyway so the design store's whole AOT surface lives behind compat.
-    The returned executable is also what :func:`aot_serialize` persists.
-    """
+    """Explicit AOT compile of a jitted callable for concrete/abstract
+    args; the returned executable is what :func:`aot_serialize`
+    persists."""
     return jitted.lower(sample_args).compile()
 
 
-def aot_serialize(compiled=None, jitted=None, sample_args=None):
-    """Serialize a compiled design to bytes; returns ``(kind, blob)``.
-
-    Pass the ``compiled`` executable from :func:`aot_compile` (preferred;
-    used verbatim by the "executable" tier) and/or the ``jitted``
-    callable + ``sample_args`` (the "stablehlo" tier re-exports from
-    them).  Returns ``(None, None)`` when the installed jax supports
-    neither — callers must then persist rankings only.
-    """
-    if _SERIALIZE_EXECUTABLE is not None and compiled is not None:
-        payload, in_tree, out_tree = _SERIALIZE_EXECUTABLE.serialize(compiled)
-        return "executable", pickle.dumps((payload, in_tree, out_tree))
-    if _EXPORT is not None and jitted is not None and sample_args is not None:
-        exported = _EXPORT.export(jitted)(sample_args)
-        return "stablehlo", exported.serialize()
-    return None, None
+def aot_serialize(compiled) -> tuple[str, bytes]:
+    """Serialize a compiled design to ``(kind, blob)``."""
+    payload, in_tree, out_tree = _se.serialize(compiled)
+    return AOT_KIND, pickle.dumps((payload, in_tree, out_tree))
 
 
 def aot_deserialize(kind: str, blob: bytes):
     """Rehydrate a persisted design into a callable executable.
 
-    ``kind`` must match what :func:`aot_serialize` returned when the blob
-    was written.  Raises ``ValueError`` when the installed jax cannot
-    load that kind (e.g. the store was written by a jax with executable
-    serialization and this one lacks it) — callers treat that as a store
-    miss and recompile from the persisted ranking.
+    Raises ``ValueError`` for a kind this module did not write — callers
+    treat that as a store miss and recompile from the persisted ranking.
     """
-    if kind == "executable":
-        if _SERIALIZE_EXECUTABLE is None:
-            raise ValueError(
-                "this jax cannot deserialize persisted XLA executables"
-            )
-        payload, in_tree, out_tree = pickle.loads(blob)
-        return _SERIALIZE_EXECUTABLE.deserialize_and_load(
-            payload, in_tree, out_tree
-        )
-    if kind == "stablehlo":
-        if _EXPORT is None:
-            raise ValueError(
-                "this jax cannot deserialize persisted StableHLO exports"
-            )
-        exported = _EXPORT.deserialize(blob)
-        return jax.jit(exported.call)
-    raise ValueError(f"unknown persisted-executable kind {kind!r}")
+    if kind != AOT_KIND:
+        raise ValueError(f"unknown persisted-executable kind {kind!r}")
+    payload, in_tree, out_tree = pickle.loads(blob)
+    return _se.deserialize_and_load(payload, in_tree, out_tree)
 
 
 # --------------------------------------------------------------------------
@@ -253,12 +89,7 @@ def is_ready(x) -> bool:
 
     True when every leaf of ``x`` reports complete — a following
     ``jax.block_until_ready`` / runner ``finalize`` returns without
-    waiting.  Newer jax exposes ``jax.Array.is_ready()``; leaves without
-    it (host arrays, older jax) are reported ready, which degrades a
-    non-blocking reap into a blocking one — still correct, just less
-    overlapped.  This is version-sensitive surface, so it lives here
-    (scripts/check_compat_imports.py policy) rather than in the
-    scheduler that polls it.
+    waiting.  Leaves without ``is_ready`` (host arrays) are ready.
     """
     for leaf in jax.tree_util.tree_leaves(x):
         ready = getattr(leaf, "is_ready", None)
@@ -284,12 +115,50 @@ def element_block_spec(
     Blocked (default) indexing places block ``i`` at ``index_map(i) *
     block_shape`` — it cannot express overlapping input windows (block
     stride != block size), which the fused stencil kernel needs for its
-    halo rows.  Newer jax spells this ``pl.Element`` per dimension; jax
-    0.4.37 spells it ``indexing_mode=pl.Unblocked()``.
+    halo rows.
     """
-    shape = tuple(int(n) for n in block_shape)
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(
-            tuple(pl.Element(n) for n in shape), index_map
-        )
-    return pl.BlockSpec(shape, index_map, indexing_mode=pl.Unblocked())
+    return pl.BlockSpec(
+        tuple(pl.Element(int(n)) for n in block_shape), index_map
+    )
+
+
+# --------------------------------------------------------------------------
+# TPU chips attached to this host, without starting a JAX backend
+# --------------------------------------------------------------------------
+
+
+def tpu_chips_on_host() -> int:
+    """Number of TPU chips on this host's PCI bus.
+
+    Reads sysfs only, so a process that must leave the chips to its
+    children (the serving router) can count them without initialising
+    a backend — initialising one would take a chip.
+    """
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+# --------------------------------------------------------------------------
+# Persistent compilation cache
+# --------------------------------------------------------------------------
+
+#: Where compiled programs are cached when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed, git-ignored path inside the checkout.  The directory is
+#: part of the cache key, so a path that moved between runs never hits.
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A ``JAX_COMPILATION_CACHE_DIR`` in the environment wins: JAX reads it
+    itself and nothing is set here.  Otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE`.  Entry points call this before their
+    first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)

@@ -33,8 +33,8 @@ from repro.core import analysis, dsl, model
 from repro.core.analysis import Diagnostic
 from repro.core.distribute import build_runner
 from repro.core.ir import PassReport, lower
-from repro.core.model import ParallelismConfig, Prediction
-from repro.core.platform import DEFAULT_TPU, TPUPlatform
+from repro.core.model import InfeasibleDesign, ParallelismConfig, Prediction
+from repro.core.platform import TPUPlatform, platform_for
 from repro.core.spec import StencilSpec
 
 
@@ -169,8 +169,7 @@ def autotune(
     lowered = lower(spec_in)
     spec = lowered.spec  # ranking AND executors consume the optimized trees
     if platform is None:
-        n_avail = len(devices) if devices is not None else len(jax.devices())
-        platform = DEFAULT_TPU.with_chips(n_avail)
+        platform = platform_for(devices)
     elif build:
         n_avail = len(devices) if devices is not None else len(jax.devices())
         platform = platform.with_chips(min(platform.num_chips, n_avail))
@@ -210,7 +209,7 @@ def autotune(
                     spec, pred.config, iterations=iterations,
                     devices=devices, tile_rows=tile_rows,
                 )
-            except ValueError as e:  # a guard preflight did not predict
+            except InfeasibleDesign as e:  # a guard preflight missed
                 diags.append(Diagnostic(
                     "SASA308", "info",
                     f"candidate {pred.config} refused at build time: {e}",
@@ -250,8 +249,7 @@ def soda_baseline(
     lowered = lower(spec)
     spec = lowered.spec
     if platform is None:
-        n_avail = len(devices) if devices is not None else len(jax.devices())
-        platform = DEFAULT_TPU.with_chips(n_avail)
+        platform = platform_for(devices)
     cands = [
         p for p in model.choose_best(
             spec, platform, iterations=iterations, optimize=False
@@ -287,7 +285,7 @@ def soda_baseline(
                 spec, pred.config, iterations=iterations, devices=devices,
                 tile_rows=tile_rows,
             )
-        except ValueError as e:
+        except InfeasibleDesign as e:
             diags.append(Diagnostic(
                 "SASA308", "info",
                 f"candidate {pred.config} refused at build time: {e}",

@@ -44,7 +44,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import axis_size, pvary, shard_map
-from repro.core.model import ParallelismConfig
+from repro.core.model import InfeasibleDesign, ParallelismConfig
 from repro.core.spec import StencilSpec
 from repro.kernels.blockops import boundary_pad, fused_iterations_on_block
 
@@ -342,7 +342,7 @@ def build_runner(
         # serving keeps the wide iterations*radius periodic margin and
         # narrow-margin specs stay single-device; the auto-tuner's
         # feasibility retry falls back to the next candidate.
-        raise ValueError(
+        raise InfeasibleDesign(
             "streamed wrap margins (wrap_index_inputs) are single-device "
             "only; shard_map designs require the wide periodic margin"
         )
@@ -371,13 +371,13 @@ def build_runner(
         R_pad = math.ceil(R / k) * k
         R_k = R_pad // k
         if cfg.variant in ("spatial_r", "hybrid_r") and it * spec.radius > R_k:
-            raise ValueError(
+            raise InfeasibleDesign(
                 f"{cfg.variant} needs iter*r <= rows/device "
                 f"({it}*{spec.radius} > {R_k}); the auto-tuner excludes "
                 "such configs (halo would span multiple neighbours)"
             )
         if wrap and R_pad != R:
-            raise ValueError(
+            raise InfeasibleDesign(
                 f"periodic boundary needs rows divisible by the spatial "
                 f"degree ({R} rows over k={k} devices leaves "
                 f"{R_pad - R} padding rows that would break the "
@@ -385,7 +385,7 @@ def build_runner(
                 "the next candidate"
             )
         if boundary.kind == "replicate" and (k - 1) * R_k > R - 1:
-            raise ValueError(
+            raise InfeasibleDesign(
                 f"replicate boundary needs every device to own at least "
                 f"one real grid row ({R} rows over k={k} devices leaves "
                 "an all-padding shard that cannot clamp to the edge); "

@@ -34,6 +34,16 @@ from repro.core.spec import BinOp, Call, Neg, StencilSpec, walk
 VARIANTS = ("temporal", "spatial_r", "spatial_s", "hybrid_r", "hybrid_s")
 
 
+class InfeasibleDesign(ValueError):
+    """A candidate configuration that cannot be built on this pool or for
+    this spec (a guard :func:`repro.core.analysis.preflight` mirrors).
+
+    The feasibility retry loops skip to the next candidate on this error
+    only; any other exception from a build — a Pallas lowering refusal
+    among them — propagates.
+    """
+
+
 @dataclasses.dataclass(frozen=True)
 class ParallelismConfig:
     """A point in the SASA design space."""

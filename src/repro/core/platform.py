@@ -80,4 +80,35 @@ class CPUPlatform:
 
 
 DEFAULT_FPGA = FPGAPlatform()
-DEFAULT_TPU = TPUPlatform()
+
+#: Chip descriptions keyed by ``jax.Device.device_kind``.  A TPU whose kind
+#: is missing here is an error (:func:`platform_for`), never a default.
+TPU_PLATFORMS = {
+    "TPU v5 lite": TPUPlatform(name="tpu-v5e"),
+}
+DEFAULT_TPU = TPU_PLATFORMS["TPU v5 lite"]
+
+
+def platform_for(devices=None) -> TPUPlatform:
+    """The modelled platform of a device pool, sized to its device count.
+
+    On a TPU backend the chip is looked up by ``device_kind`` in
+    :data:`TPU_PLATFORMS` and an unknown kind raises.  Any other backend
+    (the XLA-CPU test hosts) ranks against :data:`DEFAULT_TPU`, the chip
+    the designs are built for.
+    """
+    import jax
+
+    devices = list(devices) if devices is not None else jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        return DEFAULT_TPU.with_chips(len(devices))
+    try:
+        chip = TPU_PLATFORMS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no platform description for TPU device kind "
+            f"{dev.device_kind!r} (known: {sorted(TPU_PLATFORMS)}); add "
+            "its constants to repro.core.platform.TPU_PLATFORMS"
+        ) from None
+    return chip.with_chips(len(devices))

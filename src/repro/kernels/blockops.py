@@ -27,10 +27,12 @@ re-wrapped in-block each stage.
 """
 from __future__ import annotations
 
+import functools
 from typing import Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.spec import (
     Boundary,
@@ -121,19 +123,70 @@ def boundary_fixup(
         return jnp.where(mask, block, jnp.asarray(boundary.value, block.dtype))
     out = block
     if kind == "replicate":
-        rows = jnp.arange(shape[0]) + row0
-        tgt = jnp.clip(jnp.clip(rows, 0, grid_shape[0] - 1) - row0,
-                       0, shape[0] - 1)
-        out = jnp.take(out, tgt, axis=0)
+        clip = (
+            (lambda v: min(max(v, 0), shape[0] - 1))
+            if isinstance(row0, (int, np.integer))
+            else (lambda v: jnp.clip(v, 0, shape[0] - 1))
+        )
+        out = _clamp_copy(
+            out, 0, clip(-row0), clip(grid_shape[0] - 1 - row0)
+        )
     for d in range(1, len(shape)):
         pad = col_pads[d - 1]
         size = grid_shape[d]
-        cols = jnp.arange(shape[d]) - pad
+        hi = min(max(pad + size - 1, 0), shape[d] - 1)
         if kind == "replicate":
-            tgt = jnp.clip(cols, 0, size - 1) + pad
+            out = _clamp_copy(out, d, min(pad, shape[d] - 1), hi)
         else:  # periodic
-            tgt = jnp.mod(cols, size) + pad
-        out = jnp.take(out, jnp.clip(tgt, 0, shape[d] - 1), axis=d)
+            cols = np.arange(shape[d])
+            tgt = np.clip(np.mod(cols - pad, size) + pad, 0, shape[d] - 1)
+            out = _static_shift_copy(out, d, tgt - cols)
+    return out
+
+
+def _clamp_copy(x: jnp.ndarray, axis: int, lo, hi) -> jnp.ndarray:
+    """``take(x, clip(i, lo, hi), axis)``: cells before index ``lo`` copy
+    the slice at ``lo``, cells after ``hi`` the slice at ``hi``.
+
+    Written as selects over axis reductions because the Mosaic lowering
+    has no general gather and no dynamic slice of a value.  ``lo``/``hi``
+    are Python ints (read by static slicing) or traced int32 values
+    broadcastable against ``x`` (read by a masked max over ``axis``,
+    which copies the one selected cell exactly, NaN and -0.0 included).
+    """
+    coords = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    lowest = jnp.asarray(
+        -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+        else jnp.iinfo(x.dtype).min,
+        x.dtype,
+    )
+
+    def at(i):
+        if isinstance(i, (int, np.integer)):
+            return jax.lax.slice_in_dim(x, int(i), int(i) + 1, axis=axis)
+        return jnp.max(
+            jnp.where(coords == i, x, lowest), axis=axis, keepdims=True
+        )
+
+    return jnp.where(coords < lo, at(lo), jnp.where(coords > hi, at(hi), x))
+
+
+def _static_shift_copy(
+    x: jnp.ndarray, axis: int, shifts: np.ndarray
+) -> jnp.ndarray:
+    """``take(x, arange(n) + shifts, axis)`` for a static per-index shift
+    vector: one static roll and select per distinct shift (the periodic
+    belt needs two or three), since Mosaic lowers no gather."""
+    coords = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    out = x
+    for sh in sorted(set(int(v) for v in shifts) - {0}):
+        hit = np.flatnonzero(shifts == sh)
+        sel = (coords >= int(hit[0])) & (coords <= int(hit[-1]))
+        if hit[-1] - hit[0] + 1 != len(hit):      # not one contiguous run
+            sel = functools.reduce(
+                jnp.logical_or, [coords == int(i) for i in hit]
+            )
+        out = jnp.where(sel, jnp.roll(x, -sh, axis=axis), out)
     return out
 
 
@@ -171,12 +224,11 @@ def streamed_halo_fixup(
     block-local shift and clip above preserves that form, so the gather
     is equivalent to *static slicing*: rows below ``lo`` copy row ``lo``,
     rows above ``hi`` copy row ``hi``, the middle is identity.  That is
-    what this helper emits — two ``dynamic_index_in_dim`` broadcasts and
-    two ``where`` selects per axis instead of a ``take_along_axis``
-    gather, which keeps the inner loop on the TPU's statically-addressed
-    VMEM path (gathers lower to scalar loops on the VPU).  All-constant
-    filler maps are the degenerate ``lo == hi`` clamp and come out of the
-    same select path.
+    what this helper emits — the clamp's ``lo``/``hi`` read off the map
+    by reductions along the axis, then :func:`_clamp_copy`'s selects
+    instead of a ``take_along_axis`` gather, which the Mosaic lowering
+    does not offer.  All-constant filler maps are the degenerate
+    ``lo == hi`` clamp and come out of the same select path.
     """
     names = spec.halo_index_inputs
     out = block
@@ -184,14 +236,9 @@ def streamed_halo_fixup(
         idx = env[name]
         tgt = idx - row0 if d == 0 else idx + col_pads[d - 1]
         tgt = jnp.clip(tgt, 0, out.shape[d] - 1).astype(jnp.int32)
-        lo = jnp.min(tgt)
-        hi = jnp.max(tgt)
-        coords = jax.lax.broadcasted_iota(jnp.int32, out.shape, d)
-        at_lo = jax.lax.dynamic_index_in_dim(out, lo, axis=d, keepdims=True)
-        at_hi = jax.lax.dynamic_index_in_dim(out, hi, axis=d, keepdims=True)
-        out = jnp.where(
-            coords < lo, at_lo, jnp.where(coords > hi, at_hi, out)
-        )
+        lo = jnp.min(tgt, axis=d, keepdims=True)
+        hi = jnp.max(tgt, axis=d, keepdims=True)
+        out = _clamp_copy(out, d, lo, hi)
     return out
 
 
@@ -237,18 +284,25 @@ def fused_iterations_on_block(
             for n, a in env.items()
         }
     env = {n: fixup(a) for n, a in env.items()}
-    cur = env[spec.iterate_input]
-    for _ in range(s):
-        env[spec.iterate_input] = cur
+
+    def iteration(_, cur):
         stage_env = dict(env)
+        stage_env[spec.iterate_input] = cur
         for stage in spec.stages:
             out = _block_stage(stage, stage_env)
             if streamed:
                 out = streamed_halo_fixup(out, stage_env, spec, row0, col_pads)
             out = fixup(out)  # the boundary is re-imposed at every stage
             stage_env[stage.name] = out
-        cur = stage_env[spec.output_name]
-    return cur
+        return stage_env[spec.output_name]
+
+    # A rolled loop: the block keeps its shape across iterations, and
+    # Mosaic's compile time grows with the unrolled body (a 3D tile at
+    # s=4 took 90 s to compile unrolled, against 13 s at s=1).
+    cur = env[spec.iterate_input]
+    if s == 1:
+        return iteration(0, cur)
+    return jax.lax.fori_loop(0, s, iteration, cur)
 
 
 def wrap_round_fixup(

@@ -38,13 +38,13 @@ def stencil_run(
     s: int = 1,
     tile_rows: int = 256,
     backend: str = "jnp",
-    interpret: bool = True,
+    interpret: bool | None = None,
     align_cols: int = 1,
 ) -> jnp.ndarray:
     """Run the stencil to completion with fusion depth ``s``.
 
     backend: 'ref' (oracle), 'jnp' (fused dense), 'pallas' (TPU kernel;
-    interpret=True executes the kernel body on CPU for validation).
+    ``interpret=None`` compiles it on a TPU and interprets it elsewhere).
     """
     it = spec.iterations if iterations is None else iterations
     if backend == "ref":

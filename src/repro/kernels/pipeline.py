@@ -55,7 +55,11 @@ from repro.kernels.blockops import (
     fused_iterations_on_block,
     wrap_round_fixup,
 )
-from repro.kernels.stencil import plan_blocks
+from repro.kernels.stencil import (
+    COMPILER_PARAMS,
+    plan_blocks,
+    resolve_interpret,
+)
 
 
 def _pad_host_batched(a: jnp.ndarray, spec: StencilSpec, g: dict):
@@ -64,7 +68,7 @@ def _pad_host_batched(a: jnp.ndarray, spec: StencilSpec, g: dict):
     R = g["grid_shape"][0]
     bpads = [(0, 0), (h, h)] + [(p, p) for _ in g["col_dims"]]
     a = boundary_pad(a, bpads, spec.boundary)
-    apads = [(0, 0), (0, g["rows_padded"] - R)]
+    apads = [(0, 0), (0, g["rows_in"] - R - 2 * h)]
     for d, c in enumerate(g["col_dims"]):
         apads.append((0, g["padded_cols"][d] - c - 2 * p))
     return jnp.pad(a, apads)
@@ -87,7 +91,7 @@ def stencil_pallas_batched(
     arrays: Mapping[str, jnp.ndarray],
     s: int,
     tile_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
     align_cols: int = 1,
 ) -> jnp.ndarray:
     """One round of ``s`` fused iterations over a whole batch, with the
@@ -142,7 +146,9 @@ def stencil_pallas_batched(
         out_shape=jax.ShapeDtypeStruct(
             (B, g["rows_padded"]) + g["padded_cols"], jnp.dtype(spec.dtype)
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=COMPILER_PARAMS,
+        name="stencil_tile_batched",
     )(*padded)
 
     return out_padded[_out_slice(spec, g)]
@@ -280,14 +286,15 @@ def stencil_run_batched(
     s: int = 1,
     tile_rows: int = 256,
     backend: str = "jnp",
-    interpret: bool = True,
+    interpret: bool | None = None,
     align_cols: int = 1,
 ) -> jnp.ndarray:
     """Run the stencil to completion over a batch through the tile
     pipeline: ceil(iterations/s) rounds of the batch-in-grid executor.
 
     backend: 'jnp' (software double-buffered tile loop), 'pallas'
-    (batch-in-grid Pallas kernel; interpret=True for CPU validation).
+    (batch-in-grid Pallas kernel; ``interpret=None`` lets the backend
+    decide, see :func:`repro.kernels.stencil.resolve_interpret`).
     Specs with streamed wrap margins cap the per-round fused depth at
     ``spec.wrap_round_depth`` and re-wrap the iterate between rounds.
     """

@@ -38,8 +38,10 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import element_block_spec
+from repro.core.platform import DEFAULT_TPU
 from repro.core.spec import StencilSpec
 from repro.kernels.blockops import boundary_pad, fused_iterations_on_block
 
@@ -55,6 +57,13 @@ def plan_blocks(
 
     ``align_cols`` pads the innermost dim up to a multiple (128 on real
     TPU for lane alignment; 1 in tests to keep interpret-mode shapes small).
+
+    The input window of a tile is ``in_rows`` deep: the ``tile_rows +
+    2h`` rows the trapezoid needs, rounded up to a multiple of 8, the
+    sublane tiling the Mosaic lowering demands of a block's second-minor
+    dim.  The extra rows sit below the bottom halo, where the trapezoid
+    argument keeps them from reaching the tile's output.  ``rows_in`` is
+    the row count of the padded input array the windows stride over.
     """
     r = spec.radius
     h = s * r                      # inter-tile row halo
@@ -69,11 +78,27 @@ def plan_blocks(
         )
     n_tiles = max(math.ceil(R / tile_rows), 1)
     rows_padded = n_tiles * tile_rows
+    in_rows = _round_up(tile_rows + 2 * h, 8)
     return dict(
         r=r, h=h, p=p, grid_shape=grid_shape, col_dims=col_dims,
         padded_cols=padded_cols, n_tiles=n_tiles, rows_padded=rows_padded,
-        in_rows=tile_rows + 2 * h, tile_rows=tile_rows,
+        in_rows=in_rows, rows_in=rows_padded - tile_rows + in_rows,
+        tile_rows=tile_rows,
     )
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode for a ``pallas_call``: an explicit choice wins,
+    otherwise the default backend decides (compiled on a TPU, the
+    interpreter everywhere else).  Every kernel entry point resolves its
+    ``interpret=None`` default here, so no caller on a TPU gets the
+    interpreter by omission."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+#: Scoped-VMEM limit of every stencil ``pallas_call``: the same budget the
+#: analytical model ranks tiles against (``TPUPlatform.vmem_bytes``).
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=DEFAULT_TPU.vmem_bytes)
 
 
 def vmem_bytes_estimate(spec: StencilSpec, s: int, tile_rows: int) -> int:
@@ -98,7 +123,7 @@ def stencil_pallas(
     arrays: Mapping[str, jnp.ndarray],
     s: int,
     tile_rows: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
     align_cols: int = 1,
 ) -> jnp.ndarray:
     """Run ``s`` fused stencil iterations over the full grid via pallas_call."""
@@ -116,7 +141,7 @@ def stencil_pallas(
     def pad_host(a):
         bpads = [(h, h)] + [(p, p) for _ in g["col_dims"]]
         a = boundary_pad(a, bpads, spec.boundary)
-        apads = [(0, g["rows_padded"] - R)]
+        apads = [(0, g["rows_in"] - R - 2 * h)]
         for d, c in enumerate(g["col_dims"]):
             apads.append((0, g["padded_cols"][d] - c - 2 * p))
         return jnp.pad(a, apads)
@@ -150,7 +175,9 @@ def stencil_pallas(
         out_shape=jax.ShapeDtypeStruct(
             (g["rows_padded"],) + g["padded_cols"], jnp.dtype(spec.dtype)
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=COMPILER_PARAMS,
+        name="stencil_tile",
     )(*padded)
 
     sl = (slice(0, R),) + tuple(slice(p, p + c) for c in g["col_dims"])

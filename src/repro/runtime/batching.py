@@ -48,9 +48,10 @@ import numpy as np
 
 from repro import compat
 from repro.core.distribute import build_runner
-from repro.core.model import ParallelismConfig
+from repro.core.model import InfeasibleDesign, ParallelismConfig
 from repro.core.spec import StencilSpec
 from repro.kernels import ops, pipeline
+from repro.kernels.stencil import resolve_interpret
 from repro.runtime.bucketing import bucket_plan
 
 
@@ -165,7 +166,8 @@ def build_batched_runner(
     where the PE cascade degenerates to fused rounds on one chip with the
     fusion depth (and the analytical model's single-chip prediction)
     preserved.  The returned callable carries ``.path`` ("single_pe",
-    "tile_pipeline", or "shard_map"), ``.backend``, ``.n_devices``,
+    "tile_pipeline", or "shard_map"), ``.backend``, ``.interpret``
+    (whether a Pallas kernel runs in the interpreter), ``.n_devices``,
     ``.devices_requested``,
     and ``.degraded`` for reporting and cache keying.
     """
@@ -177,12 +179,12 @@ def build_batched_runner(
     if degraded:
         msg = degraded_message(cfg, len(avail))
         if strict:
-            raise ValueError(msg)
+            raise InfeasibleDesign(msg)
         warnings.warn(msg, DegradedDesignWarning, stacklevel=2)
 
     if n_dev <= 1:
         bk = resolve_backend(backend)
-        interp = (jax.default_backend() != "tpu") if interpret is None else interpret
+        interp = resolve_interpret(interpret) if bk == "pallas" else None
         s = max(min(cfg.s, it), 1)
         tile = cfg.tile_rows or tile_rows
 
@@ -225,7 +227,7 @@ def build_batched_runner(
 
         mesh, n_used, jitted = None, 1, fn
     else:
-        bk = "shard_map"
+        bk, interp = "shard_map", None      # XLA programs, no Pallas kernel
         inner = build_runner(
             spec, cfg, iterations=it, devices=avail[:n_dev],
             tile_rows=tile_rows, batched=True,
@@ -243,6 +245,7 @@ def build_batched_runner(
     run.iterations = it
     run.path = path
     run.backend = bk
+    run.interpret = interp
     run.mesh = mesh
     run.n_devices = n_used
     run.devices_requested = need
@@ -340,6 +343,7 @@ def build_bucket_runner(
     run.iterations = inner.iterations
     run.path = inner.path
     run.backend = inner.backend
+    run.interpret = inner.interpret
     run.n_devices = inner.n_devices
     run.devices_requested = inner.devices_requested
     run.degraded = inner.degraded

@@ -28,7 +28,7 @@ With a :class:`repro.runtime.store.DesignStore` attached
 (``DesignCache(store=...)``), both levels read through disk on a miss
 and write through on a build: rankings are persisted whole, and
 single-device batched runners persist their compiled executables per
-input signature via :mod:`repro.compat`'s AOT tier — so a fresh process
+input signature via :func:`repro.compat.aot_serialize` — so a fresh process
 pointed at a warm store serves its first result without autotuning,
 tracing, or compiling anything (docs/DESIGN.md §Persistent design
 store).
@@ -48,8 +48,8 @@ from repro.core import analysis, dsl
 from repro.core.analysis import Diagnostic, require_bucketable
 from repro.core.autotune import TunedDesign, autotune
 from repro.core.distribute import build_runner
-from repro.core.model import ParallelismConfig
-from repro.core.platform import DEFAULT_TPU, TPUPlatform
+from repro.core.model import InfeasibleDesign, ParallelismConfig
+from repro.core.platform import TPUPlatform, platform_for
 from repro.core.spec import StencilSpec
 from repro.runtime.batching import (
     build_batched_runner,
@@ -113,10 +113,10 @@ def _resolve_platform(platform, devices, clip: bool) -> TPUPlatform:
     """Mirror ``autotune``'s platform handling: an explicit platform is
     clipped to the actual device pool only when an executor will be built
     (``clip``); ranking-only studies keep the hypothetical chip count."""
-    n_avail = len(devices) if devices is not None else len(jax.devices())
     if platform is None:
-        return DEFAULT_TPU.with_chips(n_avail)
+        return platform_for(devices)
     if clip:
+        n_avail = len(devices) if devices is not None else len(jax.devices())
         return platform.with_chips(min(platform.num_chips, n_avail))
     return platform
 
@@ -163,7 +163,7 @@ class DesignCache:
     makes the cache **persistent**: rankings are read through from /
     written through to disk (a warm process never re-autotunes), and
     single-device batched runners persist their compiled executables per
-    input signature through :mod:`repro.compat`'s AOT tier, so a warm
+    input signature through :func:`repro.compat.aot_serialize`, so a warm
     replica's first dispatch deserializes instead of tracing+compiling.
     ``autotune_calls`` counts actual design-space enumerations and
     ``jit_builds`` counts actual AOT trace+compile events — both stay 0
@@ -338,7 +338,7 @@ class DesignCache:
         n_avail = len(devices) if devices is not None else len(jax.devices())
         n_used = min(cfg.devices_needed, n_avail)
         if strict and is_degraded(cfg, n_avail):
-            raise ValueError(degraded_message(cfg, n_avail))
+            raise InfeasibleDesign(degraded_message(cfg, n_avail))
         dev_key = (
             tuple(str(d) for d in devices) if devices is not None
             else ("default", n_avail, jax.default_backend())
@@ -357,7 +357,7 @@ class DesignCache:
             # known-infeasible: re-raising from the memo is a cache hit,
             # so the feasibility retry loop stays free on repeat calls
             st.hits += 1
-            raise ValueError(self._failed[key])
+            raise InfeasibleDesign(self._failed[key])
         st.misses += 1
         t0 = time.perf_counter()
         try:
@@ -372,7 +372,7 @@ class DesignCache:
                     spec, cfg, iterations=iterations, devices=devices,
                     tile_rows=tile_rows,
                 )
-        except ValueError as e:
+        except InfeasibleDesign as e:
             self._failed[key] = str(e)
             raise
         if self.store is not None and getattr(run, "jitted", None) is not None:
@@ -417,11 +417,9 @@ class DesignCache:
                 if comp is None:
                     comp = compat.aot_compile(jitted, staged)
                     self.jit_builds += 1
-                    kind, blob = compat.aot_serialize(
-                        compiled=comp, jitted=jitted, sample_args=staged,
+                    store.put_executable(
+                        store_key, sig, *compat.aot_serialize(comp)
                     )
-                    if kind is not None:
-                        store.put_executable(store_key, sig, kind, blob)
                 executables[sig] = comp
             return comp(staged)
 
@@ -430,8 +428,8 @@ class DesignCache:
             return inner_finalize(dispatch(inner_stage(arrays)))
 
         for attr in (
-            "spec", "cfg", "iterations", "path", "backend", "mesh",
-            "n_devices", "devices_requested", "degraded", "jitted",
+            "spec", "cfg", "iterations", "path", "backend", "interpret",
+            "mesh", "n_devices", "devices_requested", "degraded", "jitted",
         ):
             setattr(persistent_run, attr, getattr(run, attr))
         persistent_run.stage = inner_stage
@@ -439,6 +437,8 @@ class DesignCache:
         persistent_run.finalize = inner_finalize
         persistent_run.ready = getattr(run, "ready", compat.is_ready)
         persistent_run.store_key = store_key
+        # input signature -> executable, compiled or loaded from the store
+        persistent_run.executables = executables
         return persistent_run
 
     # ------------------------------------------------------------------
@@ -503,7 +503,7 @@ class DesignCache:
                 )
                 chosen = pred
                 break
-            except ValueError as e:
+            except InfeasibleDesign as e:
                 diags.append(Diagnostic(
                     "SASA308", "info",
                     f"candidate {pred.config} refused at build time: {e}",

@@ -11,11 +11,9 @@ that N replica processes can share:
   * **design entries** — the autotune ranking (the lowered spec + the
     full :class:`repro.core.model.Prediction` list), so a warm start
     never re-enumerates the design space;
-  * **executable entries** — compiled executables serialized through
-    :mod:`repro.compat`'s AOT tier (whole XLA executables when the
-    installed jax supports it, portable StableHLO otherwise, rankings
-    only when neither is available), one file per compiled input
-    signature, so a warm replica reaches its first bitwise-identical
+  * **executable entries** — whole compiled executables serialized
+    through :func:`repro.compat.aot_serialize`, one file per compiled
+    input signature, so a warm replica reaches its first bitwise-identical
     result without tracing or compiling anything;
   * **telemetry** — the cache's per-key :class:`KeyStats` and each
     registration's per-bucket :class:`BucketStats` counters, restored on
@@ -77,11 +75,14 @@ _MAGIC = b"SASA-STORE\x01"
 
 
 def environment_tag(backend: str | None = None) -> str:
-    """The invalidation unit: schema x jax version x backend."""
-    return (
-        f"schema{SCHEMA_VERSION}-jax{jax.__version__}-"
-        f"{backend or jax.default_backend()}"
-    )
+    """The invalidation unit: schema x jax version x backend, plus the
+    device kind on a TPU (an executable compiled for one chip generation
+    does not load on another)."""
+    backend = backend or jax.default_backend()
+    tag = f"schema{SCHEMA_VERSION}-jax{jax.__version__}-{backend}"
+    if backend == "tpu":
+        tag += "-" + jax.devices()[0].device_kind.replace(" ", "_")
+    return tag
 
 
 def _digest(payload: str, n: int = 24) -> str:

@@ -22,6 +22,21 @@ import sys
 import threading
 
 
+def device_report() -> dict:
+    """What this process runs on: JAX's platform, device kind and ids
+    of its local devices, and the TPU chip it was bound to (``None``
+    when unbound), which the router checks for sharing."""
+    import jax
+
+    devs = jax.local_devices()
+    return {
+        "platform": devs[0].platform,
+        "kind": devs[0].device_kind,
+        "ids": [d.id for d in devs],
+        "chip": os.environ.get("TPU_VISIBLE_CHIPS"),
+    }
+
+
 def _worker(args) -> int:
     # Claim fd 1 for the protocol BEFORE importing jax: anything that
     # prints to stdout afterwards lands on stderr instead of the wire.
@@ -29,10 +44,12 @@ def _worker(args) -> int:
     os.dup2(2, 1)
     sys.stdout = sys.stderr
 
+    from repro.compat import use_compile_cache
     from repro.serve.engine import StencilRequest, StencilServer
     from repro.serve.router import read_frame, write_frame
     from repro.serve.scheduler import StencilScheduler
 
+    use_compile_cache()
     server = StencilServer(
         max_batch=args.max_batch,
         max_inflight=args.max_inflight,
@@ -91,6 +108,7 @@ def _worker(args) -> int:
             elif op == "ping":
                 reply(msg["id"], True, result={
                     "pid": os.getpid(),
+                    "device": device_report(),
                     "scheduler": scheduler.stats(),
                 })
             elif op == "drain":
@@ -115,14 +133,15 @@ def _demo(args) -> int:
 
     from repro.configs import stencils
     from repro.serve.engine import StencilRequest
-    from repro.serve.router import StencilRouter
+    from repro.serve.router import StencilRouter, tpu_host_chips
 
+    replicas = args.replicas or tpu_host_chips() or 2
     rng = np.random.default_rng(0)
     spec = stencils.jacobi2d(shape=(32, 16), iterations=2)
     store = args.store or tempfile.mkdtemp(prefix="sasa-store-")
-    print(f"router: {args.replicas} replicas over store {store}")
+    print(f"router: {replicas} replicas over store {store}")
     with StencilRouter(
-        store, replicas=args.replicas, max_batch=args.max_batch,
+        store, replicas=replicas, max_batch=args.max_batch,
     ) as router:
         router.register("jacobi", spec)
         reqs = [
@@ -138,6 +157,7 @@ def _demo(args) -> int:
         for name, info in router.ping().items():
             sched = info.get("scheduler", {})
             print(f"  {name}: healthy={info.get('healthy')} "
+                  f"device={info.get('device')} "
                   f"completed={sched.get('completed')}")
     return 0
 
@@ -156,8 +176,9 @@ def main(argv=None) -> int:
     parser.add_argument("--max-inflight", type=int, default=2)
     parser.add_argument("--bucketing", action="store_true")
     parser.add_argument("--warmup", action="store_true")
-    parser.add_argument("--replicas", type=int, default=2,
-                        help="demo mode: fleet size")
+    parser.add_argument("--replicas", type=int, default=None,
+                        help="demo mode: fleet size (default: one per "
+                             "TPU chip, or 2 off the TPU)")
     args = parser.parse_args(argv)
     if args.worker:
         if not args.store:

@@ -18,6 +18,12 @@ story) and this module adds the serving half:
     serving a design keeps serving it (compiled buckets stay hot and the
     batcher sees coherent traffic), and when the replica set changes
     only that replica's designs move.
+  * **one chip per worker** — on a TPU host each worker is bound to a
+    chip of its own through libtpu's per-process visibility settings
+    (:func:`chip_env`); the router refuses more replicas than chips
+    before spawning, and refuses a fleet whose workers report a shared
+    chip or a non-TPU device (:func:`check_fleet`).  The router process
+    itself never starts a JAX backend: a chip belongs to one process.
   * **health & handoff** — a dead worker (crash, EOF, kill) is detected
     by its reader thread; its in-flight submissions are **re-routed to
     surviving replicas** (requests are retained router-side until their
@@ -43,6 +49,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro import compat
 from repro.runtime.cache import _as_spec, structural_fingerprint
 from repro.serve.engine import StencilRequest
 
@@ -72,6 +79,52 @@ def read_frame(stream):
     if len(body) < n:
         return None
     return pickle.loads(body)
+
+
+def tpu_host_chips() -> int:
+    """TPU chips the workers of this host would run on: the chips on
+    the PCI bus, or 0 where ``JAX_PLATFORMS`` keeps JAX off the TPU.
+    Reads sysfs only, so the router stays off every JAX backend."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return compat.tpu_chips_on_host()
+
+
+def chip_env(chip: int) -> dict:
+    """libtpu's per-process visibility: the process sees chip ``chip``
+    alone, as a one-chip slice of its own."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
+def check_fleet(devices: dict, tpu_host: bool) -> None:
+    """Refuse a fleet whose ``ping`` device reports (replica name ->
+    :func:`repro.serve.__main__.device_report`) show a worker off the TPU
+    on a TPU host, a worker holding more than one chip, or two workers
+    on one chip.  JAX numbers the one chip a bound process sees as
+    device 0, so a chip is told apart by the binding the worker runs
+    under."""
+    owner: dict = {}
+    for name, dev in devices.items():
+        if dev["platform"] != "tpu":
+            if tpu_host:
+                raise ValueError(
+                    f"{name} runs on {dev['platform']!r} on a TPU host"
+                )
+            continue
+        if len(dev["ids"]) != 1:
+            raise ValueError(
+                f"{name} holds {len(dev['ids'])} TPU devices; a replica "
+                "is bound to one chip"
+            )
+        chip = dev["chip"]
+        if chip in owner:
+            raise ValueError(f"{name} and {owner[chip]} share TPU chip {chip}")
+        owner[chip] = name
 
 
 class ReplicaDied(ConnectionError):
@@ -136,7 +189,9 @@ class StencilRouter:
     ``max_inflight`` configure each worker's server.  Workers inherit
     this process's environment plus a ``PYTHONPATH`` that makes
     ``repro`` importable, so the router works from a source checkout
-    without installation.
+    without installation.  On a TPU host ``replicas`` may not exceed the
+    chips, and worker ``i`` is bound to chip ``i``; ``devices`` maps
+    each replica to the device its construction-time ping reported.
     """
 
     def __init__(
@@ -151,6 +206,12 @@ class StencilRouter:
     ):
         if replicas < 1:
             raise ValueError(f"need >= 1 replica, got {replicas}")
+        n_chips = tpu_host_chips()
+        if n_chips and replicas > n_chips:
+            raise ValueError(
+                f"{replicas} replicas on a host with {n_chips} TPU chip(s): "
+                "each replica needs a chip of its own"
+            )
         self.store_dir = str(store_dir)
         self.max_batch = max_batch
         self._lock = threading.Lock()
@@ -184,7 +245,7 @@ class StencilRouter:
         for i in range(replicas):
             proc = subprocess.Popen(
                 argv, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                env=env,
+                env={**env, **chip_env(i)} if n_chips else env,
             )
             replica = _Replica(f"replica-{i}", proc)
             replica.reader = threading.Thread(
@@ -193,10 +254,19 @@ class StencilRouter:
             )
             replica.reader.start()
             self._replicas.append(replica)
-        # health-check now: a worker that can't even import dies here,
-        # at construction, not at the first request
-        for replica in self._replicas:
-            self._control(replica, {"op": "ping"}).result(spawn_timeout_s)
+        # health-check now: a worker that can't even import (or reach
+        # its chip) dies here, at construction, not at the first request
+        self.devices = {}
+        try:
+            for replica in self._replicas:
+                pong = self._control(replica, {"op": "ping"})
+                self.devices[replica.name] = pong.result(
+                    spawn_timeout_s
+                )["device"]
+            check_fleet(self.devices, tpu_host=bool(n_chips))
+        except BaseException:
+            self.close()
+            raise
 
     # ------------------------------------------------------------------
     # wire plumbing

@@ -1,14 +1,12 @@
-"""The jax-version shim: every shimmed API must work on the installed jax."""
+"""The jax shim: every API funnelled through repro.compat works on the
+installed jax."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
-
-
-def test_version_policy():
-    assert compat.JAX_VERSION >= compat.MIN_SUPPORTED_JAX
 
 
 def test_axis_size_is_concrete_under_shard_map():
@@ -25,21 +23,16 @@ def test_axis_size_is_concrete_under_shard_map():
     np.testing.assert_array_equal(np.asarray(out), np.ones(4))
 
 
-def test_shard_map_accepts_both_rep_flag_spellings():
-    mesh = Mesh(np.array(jax.devices()[:1]), ("a",))
-    x = jnp.arange(4.0)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        out = compat.shard_map(
-            lambda v: v + 1, mesh=mesh, in_specs=(P("a"),),
-            out_specs=P("a"), **kw
-        )(x)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(x) + 1)
-
-
 def test_pvary_is_identity_shaped():
-    x = jnp.ones((3, 2))
-    y = compat.pvary(x, ("a",)) if compat.JAX_VERSION < (0, 5) else x
-    assert y.shape == x.shape
+    """pvary only retypes a value as device-varying: same shape, same
+    values, inside shard_map where the cast is defined."""
+    mesh = Mesh(np.array(jax.devices()[:1]), ("a",))
+    x = jnp.arange(6.0).reshape(3, 2)
+    out = compat.shard_map(
+        lambda v: compat.pvary(v, ("a",)), mesh=mesh, in_specs=(P(),),
+        out_specs=P("a"),
+    )(x)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
 def test_element_block_spec_overlapping_windows():
@@ -63,3 +56,32 @@ def test_element_block_spec_overlapping_windows():
         interpret=True,
     )(x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x[h:h + R]))
+
+
+def test_use_compile_cache_follows_env(monkeypatch, tmp_path):
+    """The env var wins and is left to JAX; otherwise one fixed in-repo
+    path, the same on every call."""
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compat.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compat.use_compile_cache()
+        assert got == str(compat.DEFAULT_COMPILE_CACHE) == compat.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == got
+        assert compat.DEFAULT_COMPILE_CACHE.parent.joinpath("src").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_aot_roundtrip_and_unknown_kind():
+    f = jax.jit(lambda x: x * 2 + 1)
+    x = jnp.arange(8.0)
+    kind, blob = compat.aot_serialize(compat.aot_compile(f, x))
+    assert kind == compat.AOT_KIND
+    np.testing.assert_array_equal(
+        np.asarray(compat.aot_deserialize(kind, blob)(x)), np.asarray(f(x))
+    )
+    with pytest.raises(ValueError, match="unknown persisted-executable"):
+        compat.aot_deserialize("stablehlo", blob)

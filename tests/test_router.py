@@ -15,9 +15,11 @@ import pytest
 from repro.configs import stencils
 from repro.kernels import ref
 from repro.serve import StencilRequest
+from repro.serve import router as router_mod
 from repro.serve.router import (
     ReplicaDied,
     StencilRouter,
+    check_fleet,
     read_frame,
     write_frame,
 )
@@ -77,6 +79,9 @@ def test_worker_protocol_roundtrip(tmp_path):
         pong = read_frame(proc.stdout)
         assert pong["id"] == 0 and pong["ok"]
         assert pong["result"]["pid"] == proc.pid
+        assert pong["result"]["device"] == {
+            "platform": "cpu", "kind": "cpu", "ids": [0], "chip": None,
+        }
 
         write_frame(proc.stdin, {
             "id": 1, "op": "register", "name": "jac", "spec": spec,
@@ -118,6 +123,8 @@ def test_router_fleet_serves_and_health_checks(tmp_path):
             )
         health = router.ping()
         assert set(health) == {"replica-0", "replica-1"}
+        assert set(router.devices) == set(health)
+        assert all(d["platform"] == "cpu" for d in router.devices.values())
         assert all(info["healthy"] for info in health.values())
         served = sum(
             info["scheduler"]["completed"] for info in health.values()
@@ -191,3 +198,52 @@ def test_router_fails_cleanly_with_no_survivors(tmp_path):
             router.submit(grid_request("jac", spec))
     finally:
         router.close()
+
+
+def _dev(platform="tpu", ids=(0,), chip="0"):
+    return {"platform": platform, "kind": "TPU v5 lite" if platform == "tpu"
+            else "cpu", "ids": list(ids), "chip": chip}
+
+
+@pytest.mark.parametrize("devices, tpu_host, refusal", [
+    ({"a": _dev(chip="0"), "b": _dev(chip="1")}, True, None),
+    ({"a": _dev("cpu", chip=None), "b": _dev("cpu", chip=None)}, False, None),
+    ({"a": _dev(chip="0"), "b": _dev(chip="0")}, True, "share TPU chip"),
+    ({"a": _dev(chip=None), "b": _dev(chip=None)}, True, "share TPU chip"),
+    ({"a": _dev(chip="0"), "b": _dev("cpu", chip="1")}, True,
+     "runs on 'cpu' on a TPU host"),
+    ({"a": _dev(ids=(0, 1, 2, 3), chip=None)}, True, "holds 4 TPU devices"),
+])
+def test_check_fleet(devices, tpu_host, refusal):
+    if refusal is None:
+        check_fleet(devices, tpu_host)
+    else:
+        with pytest.raises(ValueError, match=refusal):
+            check_fleet(devices, tpu_host)
+
+
+def test_router_binds_one_chip_per_worker_and_refuses_extra(
+    monkeypatch, tmp_path
+):
+    """More replicas than chips is refused before any worker starts;
+    otherwise worker i is spawned bound to chip i."""
+    spawned = []
+
+    def fake_popen(argv, env=None, **kw):
+        spawned.append(env)
+        raise OSError("not spawning in this test")
+
+    monkeypatch.setattr(router_mod, "tpu_host_chips", lambda: 2)
+    monkeypatch.setattr(router_mod.subprocess, "Popen", fake_popen)
+    with pytest.raises(ValueError, match="chip of its own"):
+        StencilRouter(tmp_path / "store", replicas=3)
+    assert spawned == []
+    with pytest.raises(OSError):
+        StencilRouter(tmp_path / "store", replicas=2)
+    assert spawned[0]["TPU_VISIBLE_CHIPS"] == "0"
+    assert spawned[0]["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+
+def test_cpu_hosts_bind_no_chip(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert router_mod.tpu_host_chips() == 0

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from repro.configs import stencils
 from repro.core import autotune, soda_baseline
-from repro.core.model import ParallelismConfig
+from repro.core.model import InfeasibleDesign, ParallelismConfig
 from repro.kernels import ref
 from repro.runtime import (
     DegradedDesignWarning,
@@ -228,7 +228,7 @@ def test_soda_baseline_retries_infeasible_configs(monkeypatch):
     def flaky(spec_, cfg, **kw):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise ValueError("synthetic infeasible temporal config")
+            raise InfeasibleDesign("synthetic infeasible temporal config")
         return real(spec_, cfg, **kw)
 
     monkeypatch.setattr(at, "build_runner", flaky)
@@ -251,7 +251,7 @@ def test_soda_baseline_all_infeasible_raises(monkeypatch):
     at = sys.modules["repro.core.autotune"]
 
     def broken(*a, **k):
-        raise ValueError("synthetic: nothing fits")
+        raise InfeasibleDesign("synthetic: nothing fits")
 
     monkeypatch.setattr(at, "build_runner", broken)
     spec = stencils.jacobi2d(shape=(16, 8), iterations=2)
@@ -303,7 +303,7 @@ def test_cache_distinguishes_specs_and_options():
 
 
 def test_infeasible_configs_are_memoized(monkeypatch):
-    """A ValueError-raising config must not cost a rebuild attempt (or a
+    """An InfeasibleDesign-raising config must not cost a rebuild attempt (or a
     cache miss) on repeat calls — hit stays True for identical lookups."""
     import repro.runtime.cache as cache_mod
 
@@ -315,7 +315,7 @@ def test_infeasible_configs_are_memoized(monkeypatch):
     def flaky_build(spec_, cfg, **kw):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise ValueError("synthetic infeasible top config")
+            raise InfeasibleDesign("synthetic infeasible top config")
         return real(spec_, cfg, **kw)
 
     monkeypatch.setattr(cache_mod, "build_batched_runner", flaky_build)
@@ -325,6 +325,31 @@ def test_infeasible_configs_are_memoized(monkeypatch):
     c2 = cache.get_or_build(spec)            # both levels + the failure memo
     assert c2.hit
     assert calls["n"] == builds_after_first  # no re-attempt of the failure
+
+
+@pytest.mark.parametrize("entry", ["cache", "autotune"])
+def test_build_errors_other_than_infeasibility_propagate(monkeypatch, entry):
+    """A plain ValueError from a build (what a Pallas lowering refusal
+    raises) is a fault, not a skipped candidate: it surfaces as itself
+    instead of being folded into "no feasible configuration"."""
+    import sys
+
+    import repro.runtime.cache as cache_mod
+
+    at = sys.modules["repro.core.autotune"]
+
+    def refused(*a, **k):
+        raise ValueError("synthetic lowering refusal")
+
+    spec = stencils.jacobi2d(shape=(16, 8), iterations=2)
+    if entry == "cache":
+        monkeypatch.setattr(cache_mod, "build_batched_runner", refused)
+        call = lambda: DesignCache().get_or_build(spec)       # noqa: E731
+    else:
+        monkeypatch.setattr(at, "build_runner", refused)
+        call = lambda: autotune(spec, tile_rows=8)            # noqa: E731
+    with pytest.raises(ValueError, match="synthetic lowering refusal"):
+        call()
 
 
 def test_cached_design_runs_correctly():
