@@ -29,6 +29,10 @@ Every runner exposes three dispatch phases for the async serving loop —
 ``run.stage(arrays)`` (host -> device placement), ``run.dispatch(staged)``
 (enqueue without blocking), ``run.finalize(out)`` (block + gather to
 numpy) — with ``run(arrays)`` the validated synchronous composition.
+On single-device runners each phase is a profiler span on the host
+thread that runs it (``sasa.stage``, ``sasa.dispatch``,
+``sasa.finalize``; see :mod:`repro.serve.engine`); the shard_map
+runners' phases carry none.
 
 :func:`build_bucket_runner` wraps a runner compiled for a padded canonical
 **bucket** shape so it serves any grid that fits inside the bucket, with
@@ -215,15 +219,19 @@ def build_batched_runner(
             path = "single_pe"
 
         def stage(arrays: Mapping[str, jnp.ndarray]) -> dict:
-            return {
-                n: jax.device_put(jnp.asarray(arrays[n])) for n in spec.inputs
-            }
+            with jax.profiler.TraceAnnotation("sasa.stage"):
+                return {
+                    n: jax.device_put(jnp.asarray(arrays[n]))
+                    for n in spec.inputs
+                }
 
         def dispatch(staged: Mapping[str, jnp.ndarray]) -> jnp.ndarray:
-            return fn(dict(staged))
+            with jax.profiler.TraceAnnotation("sasa.dispatch"):
+                return fn(dict(staged))
 
         def finalize(out: jnp.ndarray) -> np.ndarray:
-            return np.asarray(out)
+            with jax.profiler.TraceAnnotation("sasa.finalize"):
+                return np.asarray(out)
 
         mesh, n_used, jitted = None, 1, fn
     else:

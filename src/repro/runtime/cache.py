@@ -409,19 +409,22 @@ class DesignCache:
         executables: dict[str, object] = {}
 
         def dispatch(staged):
-            staged = dict(staged)
-            sig = batch_signature(staged)
-            comp = executables.get(sig)
-            if comp is None:
-                comp = store.get_executable(store_key, sig)
+            # replaces the inner dispatch, so it carries the span itself;
+            # a store load or compile lands inside it
+            with jax.profiler.TraceAnnotation("sasa.dispatch"):
+                staged = dict(staged)
+                sig = batch_signature(staged)
+                comp = executables.get(sig)
                 if comp is None:
-                    comp = compat.aot_compile(jitted, staged)
-                    self.jit_builds += 1
-                    store.put_executable(
-                        store_key, sig, *compat.aot_serialize(comp)
-                    )
-                executables[sig] = comp
-            return comp(staged)
+                    comp = store.get_executable(store_key, sig)
+                    if comp is None:
+                        comp = compat.aot_compile(jitted, staged)
+                        self.jit_builds += 1
+                        store.put_executable(
+                            store_key, sig, *compat.aot_serialize(comp)
+                        )
+                    executables[sig] = comp
+                return comp(staged)
 
         def persistent_run(arrays):
             validate_batch(spec, arrays)

@@ -46,11 +46,27 @@ program) and the padding's outputs are discarded.
 
 Per-design counters (``stats()``): requests served, batches dispatched,
 design-cache hit/miss for the register call, compile/warmup seconds,
-execution latency (count / total / mean / max seconds; under async
-dispatch this is staging-to-completion latency and overlapping batches'
-latencies overlap too), requests lost to dispatch faults (whose tickets
-resolve via ``failures``), and — for bucketed designs — per-bucket
-hit/miss/request counters.
+execution latency (``exec_*``: count / total / mean / max seconds of a
+batch from the start of its staging to the end of its readback; under
+async dispatch that includes the time the batch waits behind the other
+batch in flight, and overlapping batches' latencies overlap too), queue
+wait (``queued_requests``, ``queue_wait_total_s``: seconds from admission
+to the start of staging, summed over requests; only
+:class:`repro.serve.StencilScheduler` counts it, the flush path's queue
+is a barrier and leaves both at 0), requests lost to dispatch faults
+(whose tickets resolve via ``failures``), and — for bucketed designs —
+per-bucket hit/miss/request counters.
+
+The phases of a batch are profiler spans (``jax.profiler.TraceAnnotation``)
+on the host thread that runs them, so a profiler trace puts every device
+idle gap beside the program phase around it: ``sasa.prepare`` (host
+stack, pad and placement, :meth:`StencilServer._prepare`),
+``sasa.stage`` (the runner's ``device_put``), ``sasa.dispatch`` (the
+enqueue; a lazy compile lands inside it), ``sasa.finalize`` (readback to
+numpy) and ``sasa.resolve`` (unpadding the batch and resolving its
+tickets).  The five are siblings, none inside another, and split what
+``exec_*`` times.  With no profiler running a span costs about a
+microsecond.
 
 The LM token-serving engine lives in :mod:`repro.serve.lm`; its classes
 are re-exported here for backward compatibility.
@@ -97,6 +113,8 @@ class DesignCounters:
     exec_count: int = 0
     exec_total_s: float = 0.0
     exec_max_s: float = 0.0
+    queued_requests: int = 0           # requests whose queue wait is summed
+    queue_wait_total_s: float = 0.0    # admission to staging (scheduler)
 
     @property
     def exec_mean_s(self) -> float:
@@ -481,9 +499,8 @@ class StencilServer:
                     if bucket is None and not chain:
                         # legacy / monkeypatched runner: plain callable
                         out = np.asarray(runner(stacked))
-                        self._account(reg, chunk, pad,
-                                      time.perf_counter() - t0)
-                        results.update(post(out))
+                        self._complete(reg, chunk, pad, t0, post, out,
+                                       results)
                     elif self.async_dispatch:
                         out = runner.dispatch(runner.stage(stacked))
                         inflight.append(_InFlight(
@@ -495,9 +512,8 @@ class StencilServer:
                         out = runner.finalize(
                             runner.dispatch(runner.stage(stacked))
                         )
-                        self._account(reg, chunk, pad,
-                                      time.perf_counter() - t0)
-                        results.update(post(out))
+                        self._complete(reg, chunk, pad, t0, post, out,
+                                       results)
                 except Exception as e:
                     self._fail(reg, chunk, e)
         while inflight:
@@ -546,66 +562,73 @@ class StencilServer:
     def _prepare(self, reg: _Registered, bucket, chunk):
         """Host-side staging: stack (and under bucketing pad + mask) one
         micro-batch; returns (runner, stacked arrays, post, pad count)."""
-        spec = reg.spec
-        n = len(chunk)
-        pad = self.max_batch - n
-        if bucket is None:
-            # exact-shape mode: pad the batch by repeating the first grid
-            # (one compiled program per design)
-            runner = reg.cached.runner
-            stacked = {
-                name: np.stack(
-                    [np.asarray(req.arrays[name]) for _, req, _ in chunk]
-                    + [np.asarray(chunk[0][1].arrays[name])] * pad
+        with jax.profiler.TraceAnnotation("sasa.prepare"):
+            spec = reg.spec
+            n = len(chunk)
+            pad = self.max_batch - n
+            if bucket is None:
+                # exact-shape mode: pad the batch by repeating the first grid
+                # (one compiled program per design)
+                runner = reg.cached.runner
+                stacked = {
+                    name: np.stack(
+                        [np.asarray(req.arrays[name]) for _, req, _ in chunk]
+                        + [np.asarray(chunk[0][1].arrays[name])] * pad
+                    )
+                    for name in spec.inputs
+                }
+
+                def post(out):
+                    return {t: out[i] for i, (t, _, _) in enumerate(chunk)}
+
+                return runner, stacked, post, pad
+
+            entry = reg.cached.entry_for_bucket(bucket, count=n)
+            runner = entry.runner
+            plan = runner.plan
+            stacked = {}
+            for name in spec.inputs:
+                grids = [
+                    plan.place_entry(np.asarray(req.arrays[name]))
+                    for _, req, _ in chunk
+                ]
+                grids += [plan.filler_entry(name)] * pad
+                stacked[name] = np.stack(grids)
+            # per-entry streamed service arrays (mask and/or halo-index maps):
+            # grids of different shapes share the batch, each re-imposing its
+            # own real boundary in-kernel; batch-padding entries carry the
+            # plan's throwaway filler (their outputs are discarded by post())
+            service = [plan.service_entry(shape) for _, _, shape in chunk]
+            filler = plan.service_filler()
+            for sname in plan.service_names:
+                stacked[sname] = np.stack(
+                    [e[sname] for e in service] + [filler[sname]] * pad
                 )
-                for name in spec.inputs
-            }
 
             def post(out):
-                return {t: out[i] for i, (t, _, _) in enumerate(chunk)}
+                return {
+                    t: out[i][plan.out_index(shape)]
+                    for i, (t, _, shape) in enumerate(chunk)
+                }
 
             return runner, stacked, post, pad
-
-        entry = reg.cached.entry_for_bucket(bucket, count=n)
-        runner = entry.runner
-        plan = runner.plan
-        stacked = {}
-        for name in spec.inputs:
-            grids = [
-                plan.place_entry(np.asarray(req.arrays[name]))
-                for _, req, _ in chunk
-            ]
-            grids += [plan.filler_entry(name)] * pad
-            stacked[name] = np.stack(grids)
-        # per-entry streamed service arrays (mask and/or halo-index maps):
-        # grids of different shapes share the batch, each re-imposing its
-        # own real boundary in-kernel; batch-padding entries carry the
-        # plan's throwaway filler (their outputs are discarded by post())
-        service = [plan.service_entry(shape) for _, _, shape in chunk]
-        filler = plan.service_filler()
-        for sname in plan.service_names:
-            stacked[sname] = np.stack(
-                [e[sname] for e in service] + [filler[sname]] * pad
-            )
-
-        def post(out):
-            return {
-                t: out[i][plan.out_index(shape)]
-                for i, (t, _, shape) in enumerate(chunk)
-            }
-
-        return runner, stacked, post, pad
 
     def _resolve(self, infl: _InFlight, results: dict) -> None:
         """Block on one in-flight micro-batch and resolve its tickets."""
         try:
             jax.block_until_ready(infl.out)
             out = infl.finalize(infl.out)
-            self._account(infl.reg, infl.items, infl.pad,
-                          time.perf_counter() - infl.t0)
-            results.update(infl.post(out))
+            self._complete(infl.reg, infl.items, infl.pad, infl.t0,
+                           infl.post, out, results)
         except Exception as e:
             self._fail(infl.reg, infl.items, e)
+
+    def _complete(self, reg: _Registered, chunk, pad: int, t0: float,
+                  post, out, results: dict) -> None:
+        """Count a read-back batch, then unpad it into ``results``."""
+        self._account(reg, chunk, pad, time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation("sasa.resolve"):
+            results.update(post(out))
 
     def _account(self, reg: _Registered, chunk, pad: int, dt: float) -> None:
         ctr = reg.counters

@@ -30,6 +30,11 @@ design serves one ``design x bucket`` group at a fixed batch width):
     blocks (with timeout) until its micro-batch materialises; dispatch
     faults surface per ticket, never as a dropped request.  ``drain()``
     resolves every outstanding ticket; ``close()`` drains and stops.
+    Each ticket is stamped at admission, dispatch and resolution
+    (``admitted_at``, ``dispatched_at``, ``completed_at``); the gap
+    between the first two adds to the design's queue-wait counters
+    (``queued_requests``, ``queue_wait_total_s`` in the server's
+    ``stats()``).
 
 Results are **bitwise-identical** to the synchronous engine path: the
 scheduler stages through the server's own ``_prepare`` (same padding to
@@ -94,6 +99,8 @@ class Ticket:
         default=None, repr=False
     )
     _error: Exception | None = dataclasses.field(default=None, repr=False)
+    admitted_at: float | None = None      # monotonic admission stamp
+    dispatched_at: float | None = None    # monotonic start of staging
     completed_at: float | None = None     # monotonic resolution stamp
 
     def done(self) -> bool:
@@ -279,7 +286,7 @@ class StencilScheduler:
                 )
             ticket = Ticket(
                 id=self._next_id, design=request.design, lane=lane,
-                tenant=tenant, deadline=now + slo,
+                tenant=tenant, deadline=now + slo, admitted_at=now,
             )
             self._next_id += 1
             key = (request.design, bucket)
@@ -389,9 +396,17 @@ class StencilScheduler:
     def _dispatch(self, key, chunk) -> None:
         """Stage + dispatch one micro-batch through the server's own
         staging path (identical padding and runner as the sync engine,
-        hence bitwise-identical results)."""
+        hence bitwise-identical results).  Each ticket's wait from
+        admission to here adds to the design's queue-wait counters."""
         name, bucket = key
         reg = self.server._designs[name]
+        now = time.monotonic()
+        for ticket, _, _ in chunk:
+            ticket.dispatched_at = now
+        reg.counters.queued_requests += len(chunk)
+        reg.counters.queue_wait_total_s += sum(
+            now - ticket.admitted_at for ticket, _, _ in chunk
+        )
         t0 = time.perf_counter()
         try:
             runner, stacked, post, pad = self.server._prepare(
@@ -407,7 +422,7 @@ class StencilScheduler:
                 out = np.asarray(runner(stacked))
                 self.server._account(reg, chunk, pad,
                                      time.perf_counter() - t0)
-                self._resolve_chunk(chunk, post(out))
+                self._resolve_chunk(chunk, post, out)
                 self.dispatched_batches += 1
                 return
             out = runner.dispatch(runner.stage(stacked))
@@ -445,7 +460,7 @@ class StencilScheduler:
                         infl.reg, infl.chunk, infl.pad,
                         time.perf_counter() - infl.t0,
                     )
-                    self._resolve_chunk(infl.chunk, infl.post(out))
+                    self._resolve_chunk(infl.chunk, infl.post, out)
                 except Exception as e:
                     self._fail_chunk(infl.reg, infl.chunk, e)
             finally:
@@ -456,18 +471,21 @@ class StencilScheduler:
             block = False                 # only force the oldest
         return did
 
-    def _resolve_chunk(self, chunk, results: dict) -> None:
-        now = time.monotonic()
-        with self._work:
-            for ticket, _, _ in chunk:
-                ticket._result = results[ticket]
-                ticket.completed_at = now
-                if now > ticket.deadline:
-                    self.deadline_misses += 1
-                self._outstanding[ticket.tenant] -= 1
-                self.completed += 1
-                ticket._event.set()
-            self._work.notify_all()
+    def _resolve_chunk(self, chunk, post, out) -> None:
+        """Unpad a read-back batch (``post(out)``) and resolve its tickets."""
+        with jax.profiler.TraceAnnotation("sasa.resolve"):
+            results = post(out)
+            now = time.monotonic()
+            with self._work:
+                for ticket, _, _ in chunk:
+                    ticket._result = results[ticket]
+                    ticket.completed_at = now
+                    if now > ticket.deadline:
+                        self.deadline_misses += 1
+                    self._outstanding[ticket.tenant] -= 1
+                    self.completed += 1
+                    ticket._event.set()
+                self._work.notify_all()
 
     def _fail_chunk(self, reg, chunk, exc: Exception) -> None:
         reg.counters.failed_requests += len(chunk)
