@@ -32,7 +32,12 @@ numpy) — with ``run(arrays)`` the validated synchronous composition.
 On single-device runners each phase is a profiler span on the host
 thread that runs it (``sasa.stage``, ``sasa.dispatch``,
 ``sasa.finalize``; see :mod:`repro.serve.engine`); the shard_map
-runners' phases carry none.
+runners' phases carry none.  Single-device runners
+(``run.stages_grids``) also stage a batch given as per-input lists of
+grids: each grid crosses to the device once, ``pad`` slots repeat the
+first grid's device buffer, and the ``(B,) + grid`` operand is stacked
+on the device, so batch padding never crosses the host link and the
+host never copies the batch.
 
 :func:`build_bucket_runner` wraps a runner compiled for a padded canonical
 **bucket** shape so it serves any grid that fits inside the bucket, with
@@ -57,6 +62,13 @@ from repro.core.spec import StencilSpec
 from repro.kernels import ops, pipeline
 from repro.kernels.stencil import resolve_interpret
 from repro.runtime.bucketing import bucket_plan
+
+
+@jax.jit
+def _stack_grids(grids: list) -> jnp.ndarray:
+    """One ``(B,) + grid`` operand from B device-resident grids (jit
+    caches one program per batch width, grid shape and dtype)."""
+    return jnp.stack(grids)
 
 
 class DegradedDesignWarning(RuntimeWarning):
@@ -218,12 +230,21 @@ def build_batched_runner(
             fn = jax.jit(jax.vmap(one_grid))
             path = "single_pe"
 
-        def stage(arrays: Mapping[str, jnp.ndarray]) -> dict:
+        def stage(arrays: Mapping[str, jnp.ndarray], pad: int = 0) -> dict:
+            """Place one batch on the device.  An input given as one
+            array is the whole batch; one given as a list of grids is
+            sent grid by grid, ``pad`` slots repeating its first grid's
+            device buffer, and stacked on the device."""
             with jax.profiler.TraceAnnotation("sasa.stage"):
-                return {
-                    n: jax.device_put(jnp.asarray(arrays[n]))
-                    for n in spec.inputs
-                }
+                staged = {}
+                for n in spec.inputs:
+                    a = arrays[n]
+                    if isinstance(a, list):
+                        grids = jax.device_put(a)
+                        staged[n] = _stack_grids(grids + grids[:1] * pad)
+                    else:
+                        staged[n] = jax.device_put(jnp.asarray(a))
+                return staged
 
         def dispatch(staged: Mapping[str, jnp.ndarray]) -> jnp.ndarray:
             with jax.profiler.TraceAnnotation("sasa.dispatch"):
@@ -233,7 +254,7 @@ def build_batched_runner(
             with jax.profiler.TraceAnnotation("sasa.finalize"):
                 return np.asarray(out)
 
-        mesh, n_used, jitted = None, 1, fn
+        mesh, n_used, jitted, stages_grids = None, 1, fn, True
     else:
         bk, interp = "shard_map", None      # XLA programs, no Pallas kernel
         inner = build_runner(
@@ -243,6 +264,7 @@ def build_batched_runner(
         stage, dispatch, finalize = inner.stage, inner.dispatch, inner.finalize
         path, mesh, n_used = "shard_map", inner.mesh, n_dev
         jitted = None   # shard_map programs are not AOT-persistable (yet)
+        stages_grids = False    # its stage row-pads one host array per input
 
     def run(arrays: Mapping[str, jnp.ndarray]) -> np.ndarray:
         validate_batch(spec, arrays)
@@ -261,6 +283,9 @@ def build_batched_runner(
     run.stage = stage
     run.dispatch = dispatch
     run.finalize = finalize
+    # stage() also takes per-input grid lists and a pad count (see the
+    # module docstring); False: it takes one host array per input only
+    run.stages_grids = stages_grids
     # non-blocking completion poll over a dispatch()'s output: the
     # continuous-batching scheduler reaps finished micro-batches without
     # stalling its admission loop (falls back to "ready" = blocking reap

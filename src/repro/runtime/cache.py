@@ -433,6 +433,7 @@ class DesignCache:
         for attr in (
             "spec", "cfg", "iterations", "path", "backend", "interpret",
             "mesh", "n_devices", "devices_requested", "degraded", "jitted",
+            "stages_grids",
         ):
             setattr(persistent_run, attr, getattr(run, attr))
         persistent_run.stage = inner_stage

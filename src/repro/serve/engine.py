@@ -27,8 +27,8 @@ bucketed serving).  Without bucketing, requests must match the
 registered spec's exact shape (the pre-bucketing contract).
 
 **Async double-buffered dispatch** (``async_dispatch=True``, the
-default): each micro-batch is staged (host stack/pad + ``jax.device_put``)
-and dispatched without blocking; the host then stages micro-batch N+1
+default): each micro-batch is staged (to the device, see below) and
+dispatched without blocking; the host then stages micro-batch N+1
 while the device executes micro-batch N, and only blocks
 (``jax.block_until_ready`` via the runner's ``finalize``) when the
 bounded in-flight queue (``max_inflight``) is full or the flush drains.
@@ -44,6 +44,14 @@ different designs never share a batch.  Short final chunks are padded up
 to the compiled batch size (so a design compiles exactly one batched
 program) and the padding's outputs are discarded.
 
+**Staging.** In exact-shape mode the host copies nothing: each request's
+grid crosses to the device once, the padding slots repeat the first
+grid's device buffer, and the runner stacks the batch on the device
+(``runner.stages_grids``; see :mod:`repro.runtime.batching`).  A runner
+that cannot (the shard_map runners, a plain callable) gets one host
+array per input, stacked by :func:`host_batch`.  Under bucketing the
+host lays each grid into its bucket and stacks the batch.
+
 Per-design counters (``stats()``): requests served, batches dispatched,
 design-cache hit/miss for the register call, compile/warmup seconds,
 execution latency (``exec_*``: count / total / mean / max seconds of a
@@ -54,14 +62,17 @@ wait (``queued_requests``, ``queue_wait_total_s``: seconds from admission
 to the start of staging, summed over requests; only
 :class:`repro.serve.StencilScheduler` counts it, the flush path's queue
 is a barrier and leaves both at 0), requests lost to dispatch faults
-(whose tickets resolve via ``failures``), and — for bucketed designs —
-per-bucket hit/miss/request counters.
+(whose tickets resolve via ``failures``), batches stacked on the device
+(``device_batches``) and host-to-device bytes sent by staging
+(``staged_bytes``), and — for bucketed designs — per-bucket
+hit/miss/request counters.
 
 The phases of a batch are profiler spans (``jax.profiler.TraceAnnotation``)
 on the host thread that runs them, so a profiler trace puts every device
-idle gap beside the program phase around it: ``sasa.prepare`` (host
-stack, pad and placement, :meth:`StencilServer._prepare`),
-``sasa.stage`` (the runner's ``device_put``), ``sasa.dispatch`` (the
+idle gap beside the program phase around it: ``sasa.prepare``
+(:meth:`StencilServer._prepare`: the grid lists, or under bucketing the
+host placement and stack), ``sasa.stage`` (the runner's ``device_put``
+and device stack), ``sasa.dispatch`` (the
 enqueue; a lazy compile lands inside it), ``sasa.finalize`` (readback to
 numpy) and ``sasa.resolve`` (unpadding the batch and resolving its
 tickets).  The five are siblings, none inside another, and split what
@@ -115,6 +126,8 @@ class DesignCounters:
     exec_max_s: float = 0.0
     queued_requests: int = 0           # requests whose queue wait is summed
     queue_wait_total_s: float = 0.0    # admission to staging (scheduler)
+    device_batches: int = 0            # batches stacked on the device
+    staged_bytes: int = 0              # host-to-device bytes sent by stage
 
     @property
     def exec_mean_s(self) -> float:
@@ -124,6 +137,23 @@ class DesignCounters:
         d = dataclasses.asdict(self)
         d["exec_mean_s"] = self.exec_mean_s
         return d
+
+
+def host_batch(batch: Mapping, pad: int) -> dict:
+    """One host array per input of a prepared batch: a grid list is
+    stacked with its first grid repeated ``pad`` times; an array is
+    already the batch."""
+    return {
+        n: np.stack(v + v[:1] * pad) if isinstance(v, list) else v
+        for n, v in batch.items()
+    }
+
+
+def _chained(runner) -> bool:
+    """Whether ``runner`` has the stage/dispatch/finalize phases (else
+    it is a plain callable over a host batch)."""
+    return all(callable(getattr(runner, p, None))
+               for p in ("stage", "dispatch", "finalize"))
 
 
 @dataclasses.dataclass
@@ -355,13 +385,19 @@ class StencilServer:
         # (max_batch, ...) and THIS server's bucket size may be new.  When
         # the shape is already jit-cached the warmup dispatch is ~free.
         if self.warmup:
-            spec = reg.spec
-            zeros = {
-                n: np.zeros((self.max_batch,) + tuple(shape), dtype=dt)
-                for n, (dt, shape) in spec.inputs.items()
+            runner = cached.runner
+            grids = {
+                n: [np.zeros(tuple(shape), dtype=dt)]
+                for n, (dt, shape) in reg.spec.inputs.items()
             }
             t0 = time.perf_counter()
-            cached.runner(zeros)
+            if getattr(runner, "stages_grids", False):
+                # the path requests take, device stack included
+                runner.finalize(runner.dispatch(
+                    runner.stage(grids, pad=self.max_batch - 1)
+                ))
+            else:
+                runner(host_batch(grids, self.max_batch - 1))
             ctr.warmup_time_s = time.perf_counter() - t0
         self._designs[name] = reg
         return reg
@@ -488,30 +524,27 @@ class StencilServer:
                     self._resolve(inflight.popleft(), results)
                 t0 = time.perf_counter()
                 try:
-                    runner, stacked, post, pad = self._prepare(
+                    runner, batch, post, pad = self._prepare(
                         reg, bucket, chunk
                     )
-                    chain = (
-                        callable(getattr(runner, "stage", None))
-                        and callable(getattr(runner, "dispatch", None))
-                        and callable(getattr(runner, "finalize", None))
-                    )
-                    if bucket is None and not chain:
+                    if bucket is None and not _chained(runner):
                         # legacy / monkeypatched runner: plain callable
-                        out = np.asarray(runner(stacked))
+                        out = np.asarray(runner(host_batch(batch, pad)))
                         self._complete(reg, chunk, pad, t0, post, out,
                                        results)
                     elif self.async_dispatch:
-                        out = runner.dispatch(runner.stage(stacked))
+                        out = runner.dispatch(
+                            self._stage(reg, runner, batch, pad)
+                        )
                         inflight.append(_InFlight(
                             reg=reg, items=chunk, out=out,
                             finalize=runner.finalize, post=post, pad=pad,
                             t0=t0,
                         ))
                     else:
-                        out = runner.finalize(
-                            runner.dispatch(runner.stage(stacked))
-                        )
+                        out = runner.finalize(runner.dispatch(
+                            self._stage(reg, runner, batch, pad)
+                        ))
                         self._complete(reg, chunk, pad, t0, post, out,
                                        results)
                 except Exception as e:
@@ -560,28 +593,28 @@ class StencilServer:
     # ------------------------------------------------------------------
 
     def _prepare(self, reg: _Registered, bucket, chunk):
-        """Host-side staging: stack (and under bucketing pad + mask) one
-        micro-batch; returns (runner, stacked arrays, post, pad count)."""
+        """Host side of one micro-batch; returns (runner, batch, post, pad
+        count).  In exact-shape mode ``batch`` maps each input to the
+        chunk's own grids, uncopied; under bucketing to one host array
+        (grids laid into the bucket, stacked, padded, with the streamed
+        service inputs)."""
         with jax.profiler.TraceAnnotation("sasa.prepare"):
             spec = reg.spec
             n = len(chunk)
             pad = self.max_batch - n
             if bucket is None:
-                # exact-shape mode: pad the batch by repeating the first grid
-                # (one compiled program per design)
+                # exact-shape mode: staging pads the batch to max_batch by
+                # repeating the first grid (one compiled program per design)
                 runner = reg.cached.runner
-                stacked = {
-                    name: np.stack(
-                        [np.asarray(req.arrays[name]) for _, req, _ in chunk]
-                        + [np.asarray(chunk[0][1].arrays[name])] * pad
-                    )
+                grids = {
+                    name: [np.asarray(req.arrays[name]) for _, req, _ in chunk]
                     for name in spec.inputs
                 }
 
                 def post(out):
                     return {t: out[i] for i, (t, _, _) in enumerate(chunk)}
 
-                return runner, stacked, post, pad
+                return runner, grids, post, pad
 
             entry = reg.cached.entry_for_bucket(bucket, count=n)
             runner = entry.runner
@@ -612,6 +645,25 @@ class StencilServer:
                 }
 
             return runner, stacked, post, pad
+
+    def _stage(self, reg: _Registered, runner, batch, pad: int):
+        """``runner.stage`` over one prepared micro-batch, counted in
+        ``device_batches`` and ``staged_bytes``.  Grid lists go as they
+        are to a runner that stacks on the device; any other runner gets
+        :func:`host_batch`."""
+        ctr = reg.counters
+        lists = any(isinstance(v, list) for v in batch.values())
+        if lists and getattr(runner, "stages_grids", False):
+            staged = runner.stage(batch, pad=pad)
+            ctr.device_batches += 1
+        else:
+            batch = host_batch(batch, pad)
+            staged = runner.stage(batch)
+        ctr.staged_bytes += sum(
+            sum(g.nbytes for g in v) if isinstance(v, list) else v.nbytes
+            for v in batch.values()
+        )
+        return staged
 
     def _resolve(self, infl: _InFlight, results: dict) -> None:
         """Block on one in-flight micro-batch and resolve its tickets."""

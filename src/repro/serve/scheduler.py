@@ -37,10 +37,10 @@ design serves one ``design x bucket`` group at a fixed batch width):
     ``stats()``).
 
 Results are **bitwise-identical** to the synchronous engine path: the
-scheduler stages through the server's own ``_prepare`` (same padding to
-the compiled ``max_batch`` width, same streamed service inputs, same
-compiled runner), so on a fixed backend a grid's result does not depend
-on which batch — or which scheduler — carried it.
+scheduler stages through the server's own ``_prepare`` and ``_stage``
+(same padding to the compiled ``max_batch`` width, same streamed service
+inputs, same compiled runner), so on a fixed backend a grid's result does
+not depend on which batch — or which scheduler — carried it.
 
 Unit tests drive the loop deterministically: construct with
 ``start=False`` and call :meth:`StencilScheduler.step` by hand.
@@ -55,6 +55,8 @@ import time
 
 import jax
 import numpy as np
+
+from repro.serve.engine import _chained, host_batch
 
 # default SLO lanes (seconds of slack granted at admission). Tighter
 # lane -> earlier deadline -> dispatched first under contention.
@@ -150,10 +152,10 @@ class StencilScheduler:
     """Flush-free continuous batching over a :class:`StencilServer`.
 
     The scheduler owns admission and dispatch; the server contributes
-    its registrations, validation, staging (``_prepare``), counters, and
-    batch geometry (``max_batch`` / ``max_inflight``).  Both serving
-    paths can coexist on one server: the scheduler never touches the
-    server's flush queue or ticket space.
+    its registrations, validation, staging (``_prepare``, ``_stage``),
+    counters, and batch geometry (``max_batch`` / ``max_inflight``).  Both
+    serving paths can coexist on one server: the scheduler never touches
+    the server's flush queue or ticket space.
 
     ``lanes`` maps lane name -> SLO seconds (:data:`DEFAULT_LANES` when
     omitted); ``max_queue`` bounds total pending tickets; ``quota``
@@ -409,23 +411,20 @@ class StencilScheduler:
         )
         t0 = time.perf_counter()
         try:
-            runner, stacked, post, pad = self.server._prepare(
+            runner, batch, post, pad = self.server._prepare(
                 reg, bucket, chunk
             )
-            chain = (
-                callable(getattr(runner, "stage", None))
-                and callable(getattr(runner, "dispatch", None))
-                and callable(getattr(runner, "finalize", None))
-            )
-            if not chain:
+            if not _chained(runner):
                 # legacy / monkeypatched runner: synchronous plain call
-                out = np.asarray(runner(stacked))
+                out = np.asarray(runner(host_batch(batch, pad)))
                 self.server._account(reg, chunk, pad,
                                      time.perf_counter() - t0)
                 self._resolve_chunk(chunk, post, out)
                 self.dispatched_batches += 1
                 return
-            out = runner.dispatch(runner.stage(stacked))
+            out = runner.dispatch(
+                self.server._stage(reg, runner, batch, pad)
+            )
         except Exception as e:
             self._fail_chunk(reg, chunk, e)
             return
