@@ -40,7 +40,8 @@ def main(argv=None) -> int:
     from sasabench import cells, startup
 
     cell = cells.load_cell(args.workload, ROOT)
-    if not startup.start(cell.chips, "control", T_START, print):
+    if not startup.start(cell.chips, "control", T_START, print,
+                         cell.workers_hold_chips):
         return 2
     from sasabench import harness
 

@@ -108,9 +108,9 @@ class Session:
         }
 
     def window(self, rate_per_s: float, seconds: float, seed: int,
-               keep=(), trace: bool = False) -> tuple[Window, str | None]:
+               keep=(), trace: bool = False) -> tuple[Window, list[str]]:
         """Fire one window of the mix at ``rate_per_s`` and wait for its
-        answers; returns what the client saw and the trace file, if any."""
+        answers; returns what the client saw and the trace files, if any."""
         from repro.serve import Backpressure
 
         arrivals = traffic.open_loop(rate_per_s, seconds, self.shares, seed)
@@ -171,7 +171,7 @@ class Session:
             failed += 1
             latency[a.index] = math.inf
         return Window(arrivals, seconds, t0, latency, done_at, answers, late,
-                      failed), tr.path
+                      failed), tr.files
 
     def close(self) -> None:
         self.scheduler.close(timeout=STRAGGLER_S)
@@ -192,8 +192,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                                      seconds, session.shares, seed)
         checked = traffic.checked_sample(
             arrivals, int(cell.traffic["checked"]), seed, largest)
-        w, trace_file = session.window(float(cell.traffic["rate_per_s"]),
-                                       seconds, seed, checked, trace)
+        w, trace_files = session.window(float(cell.traffic["rate_per_s"]),
+                                        seconds, seed, checked, trace)
         session.scheduler.drain(timeout=STRAGGLER_S)
         after = session.counters()
     finally:
@@ -236,7 +236,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                                 for k in shapes},
         },
         window=(w.start, w.start + seconds),
-        trace_file=trace_file,
+        trace_files=trace_files,
     )
 
 

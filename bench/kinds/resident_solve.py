@@ -134,7 +134,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
             "bytes": work.stencil_bytes(cfg, shape, dispatches * ensemble),
         },
         window=(t0, t0 + elapsed),
-        trace_file=tr.path,
+        trace_files=tr.files,
     )
 
 

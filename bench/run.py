@@ -13,7 +13,8 @@ cell's end-to-end metrics, or with ``--trace 1`` its per-layer metrics
 read from a profiler trace of the window.
 
 It runs on a TPU only: where JAX finds another platform, or fewer chips
-than the cell asks for, it exits non-zero and prints no result.
+than the cell asks for (counted on the host's bus where the cell's
+worker processes hold them), it exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -44,7 +45,8 @@ def main(argv=None) -> int:
     from sasabench import cells, startup
 
     cell = cells.load_cell(args.workload, ROOT)
-    if not startup.start(cell.chips, "bench", T_START, print):
+    if not startup.start(cell.chips, "bench", T_START, print,
+                         cell.workers_hold_chips):
         return 2
     from sasabench import harness
 

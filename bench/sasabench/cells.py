@@ -11,7 +11,16 @@ later change adds a cell, a mix or a metric by adding files:
   * ``kinds/<kind>.py`` -- the driver of one kind of traffic, shared by
     every mix of that kind: ``run(...)``, which drives one run and returns
     a ``harness.Outcome``, and ``control(cell)``, a context manager that
-    puts the control in the program's place;
+    puts the control in the program's place.  A kind whose chips belong
+    to worker processes that its driver starts (one per chip, as the
+    program's router starts its replicas) says so with a module attribute
+    ``WORKERS_HOLD_CHIPS = True``.  A process that has opened a JAX
+    backend holds every chip it sees, so for such a kind the benchmark's
+    process opens none: start-up counts the chips on the host's bus, no
+    profiler starts in it, and the driver reports the workers' devices,
+    memory and traces in the ``Outcome``.  Its driver may open a backend
+    only once its workers have exited (to run the reference for
+    ``correct``, say); anything earlier takes the workers' chips;
   * ``limits/<cell>.json`` -- the limits of the comparison that decides
     ``correct``, with the readings they were set from;
   * ``layers/<metric>.py`` -- the reader of one per-layer metric, a
@@ -43,6 +52,12 @@ class Cell:
     readers: dict[str, Callable]
     step: Callable                 # the configuration's reference iteration
     kind: types.ModuleType         # the driver of the cell's traffic kind
+
+    @property
+    def workers_hold_chips(self) -> bool:
+        """Whether the chips belong to worker processes that the kind's
+        driver starts, and not to this process (``WORKERS_HOLD_CHIPS``)."""
+        return bool(getattr(self.kind, "WORKERS_HOLD_CHIPS", False))
 
 
 def load_module(path: Path, name: str):

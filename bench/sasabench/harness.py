@@ -23,17 +23,33 @@ PEAKS = Path(__file__).resolve().parents[1] / "peaks.json"
 
 @dataclasses.dataclass
 class Outcome:
-    """What a driver measured in one run."""
+    """What a driver measured in one run.
+
+    The driver of a kind whose chips belong to its worker processes
+    (``WORKERS_HOLD_CHIPS``, see ``cells.py``) gives ``device``: the
+    ``platform``, ``kind`` and ``count`` of the workers' devices, and
+    ``workers``, one dict for each worker with its ``chip``, ``pid`` and
+    ``memory_peak_bytes`` (``None`` where the worker reports none).  Its
+    own ``memory_peak_bytes`` is then ``None``: this process holds no
+    chip.  Its ``trace_files`` are the workers' traces, each clipped to
+    ``window_ns`` (``time.time_ns()`` at the window's start and end), as
+    none holds a ``bench_window`` span.  It opens a JAX backend in this
+    process only after its workers have exited: one opened earlier would
+    take their chips.  A driver that gives no ``device`` runs on this
+    process's devices.
+    """
 
     attempted: int
     failed: int
     end_to_end: dict[str, float]
     compared: dict[str, float]           # name -> reading (limits are the cell's)
-    memory_peak_bytes: int
+    memory_peak_bytes: int | None        # fullest device of this process
     counters: dict                       # program counters over the window
     work: dict                           # work counts over the window
     window: tuple[float, float]          # perf_counter at its start and end
-    trace_file: str | None = None
+    trace_files: list[str] = dataclasses.field(default_factory=list)
+    window_ns: tuple[int, int] | None = None
+    device: dict | None = None
 
 
 def memory_peak_bytes() -> int:
@@ -45,21 +61,36 @@ def memory_peak_bytes() -> int:
     return int(max(peaks, default=0))
 
 
+@dataclasses.dataclass
 class _Trace:
-    path: str | None = None
+    files: list[str] = dataclasses.field(default_factory=list)
+    window_ns: tuple[int, int] | None = None
 
 
 @contextlib.contextmanager
-def traced(on: bool):
+def traced(on: bool, workers_hold_chips: bool = False):
     """Profile the body (the measured window) when ``on``: the JAX
     profiler's trace with a ``bench_window`` span around the body.  The
     trace is written under a fresh temporary directory, whose
-    ``.xplane.pb`` the result's ``path`` names after the block."""
+    ``.xplane.pb`` the result's ``files`` name after the block.
+
+    Where the cell's chips belong to worker processes, no profiler starts
+    here: it would open a JAX backend and take the workers' chips.  The
+    result's ``window_ns`` is then the body's start and end on
+    ``time.time_ns()``'s clock, to which the driver clips the traces it
+    asks its workers for."""
     import jax
 
     result = _Trace()
     if not on:
         yield result
+        return
+    if workers_hold_chips:
+        t0 = time.time_ns()
+        try:
+            yield result
+        finally:
+            result.window_ns = (t0, time.time_ns())
         return
     out = tempfile.mkdtemp(prefix="sasabench-trace-")
     opts = jax.profiler.ProfileOptions()
@@ -70,9 +101,8 @@ def traced(on: bool):
             yield result
     finally:
         jax.profiler.stop_trace()
-        files = glob.glob(os.path.join(out, "**", "*.xplane.pb"),
-                          recursive=True)
-        result.path = files[0] if files else None
+        result.files = glob.glob(os.path.join(out, "**", "*.xplane.pb"),
+                                 recursive=True)[:1]
 
 
 def load_peaks(device_kind: str) -> dict:
@@ -99,14 +129,36 @@ def device_info() -> dict:
             "count": len(devices)}
 
 
+def device_block(outcome: Outcome) -> dict:
+    """The result line's ``device``: the workers' as the driver reports
+    them, where it does, else this process's devices.  With workers,
+    ``memory_peak_bytes`` is the fullest worker's, and ``None`` where a
+    worker reports no memory: no number is made up for it."""
+    if outcome.device is None:
+        return dict(device_info(), memory_peak_bytes=outcome.memory_peak_bytes)
+    workers = outcome.device["workers"]
+    peaks = [w["memory_peak_bytes"] for w in workers]
+    if not peaks or None in peaks:
+        log("memory_peak_bytes: null, a worker reports no device memory")
+        peak = None
+    else:
+        peak = max(peaks)
+    return {"platform": outcome.device["platform"],
+            "kind": outcome.device["kind"], "count": outcome.device["count"],
+            "memory_peak_bytes": peak, "workers": workers}
+
+
 def layer_metrics(cell, outcome: Outcome, peaks: dict | None) -> tuple[dict, dict]:
     """Per-layer metrics of a traced run, and the trace's device summary."""
     from sasabench import trace as tracing
 
     summary = None
-    if outcome.trace_file:
-        summary = tracing.reduce(outcome.trace_file, WINDOW_SPAN)
-        shutil.rmtree(Path(outcome.trace_file).parents[3], ignore_errors=True)
+    if outcome.trace_files:
+        summary = tracing.reduce(outcome.trace_files, WINDOW_SPAN,
+                                 outcome.window_ns)
+        # each as the profiler lays it out: <dir>/plugins/profile/<run>/
+        for path in outcome.trace_files:
+            shutil.rmtree(Path(path).parents[3], ignore_errors=True)
     ctx = types.SimpleNamespace(
         trace=summary, counters=outcome.counters, work=outcome.work,
         end_to_end=outcome.end_to_end, config=cell.config,
@@ -150,7 +202,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     """Run one cell and return its result line as a dict, ``compared``
     last.  The comparison's readings and limits are also the last lines
     written to standard error."""
-    peaks = load_peaks(device_info()["kind"]) if trace else None
     with compile_events() as seen:
         outcome = cell.kind.run(cell, seed, seconds, trace, t_start, log)
     w0, w1 = outcome.window
@@ -166,9 +217,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     correct = all(c["value"] <= c["limit"] for c in compared.values())
     result = {"correct": correct, "attempted": outcome.attempted,
               "failed": outcome.failed}
-    device = dict(device_info(), memory_peak_bytes=outcome.memory_peak_bytes)
+    device = device_block(outcome)
     if trace:
-        metrics, summary = layer_metrics(cell, outcome, peaks)
+        metrics, summary = layer_metrics(cell, outcome,
+                                         load_peaks(device["kind"]))
         if summary is not None and summary.devices:
             device["busy_s"] = summary.busy_s
             device["window_s"] = summary.window_s

@@ -39,7 +39,8 @@ def main(argv=None) -> int:
     from sasabench import cells, startup, stats
 
     cell = cells.load_cell(args.workload, ROOT)
-    if not startup.start(cell.chips, "sweep_knee", T_START, print):
+    if not startup.start(cell.chips, "sweep_knee", T_START, print,
+                         cell.workers_hold_chips):
         return 2
     serve = cell.kind
     t0 = time.perf_counter()
